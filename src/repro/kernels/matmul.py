@@ -30,6 +30,16 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+def _dot_f32(a: jax.Array, b: jax.Array) -> jax.Array:
+    """``a @ b`` accumulated in float32.  Float32 operands contract at full
+    float32 precision: Mosaic's default contraction rounds them to bf16
+    (on a TPU v5e that gave the n = 8192 float32 Markov chain a norm-wise
+    relative error of 4.0e-3, against 6.6e-7 at full precision)."""
+    prec = (jax.lax.Precision.HIGHEST
+            if jnp.float32 in (a.dtype, b.dtype) else None)
+    return jnp.dot(a, b, precision=prec, preferred_element_type=jnp.float32)
+
+
 def _mm_kernel(a_ref, b_ref, o_ref, acc_ref, *, nk: int):
     """Grid (i, j, k); k is the minor-most (fastest) dimension."""
     k = pl.program_id(2)
@@ -38,8 +48,7 @@ def _mm_kernel(a_ref, b_ref, o_ref, acc_ref, *, nk: int):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(a_ref[...], b_ref[...],
-                            preferred_element_type=jnp.float32)
+    acc_ref[...] += _dot_f32(a_ref[...], b_ref[...])
 
     @pl.when(k == nk - 1)
     def _done():
@@ -54,8 +63,7 @@ def _addmul_kernel(c_ref, a_ref, b_ref, o_ref, acc_ref, *, nk: int):
     def _init():
         acc_ref[...] = c_ref[...].astype(jnp.float32)
 
-    acc_ref[...] += jnp.dot(a_ref[...], b_ref[...],
-                            preferred_element_type=jnp.float32)
+    acc_ref[...] += _dot_f32(a_ref[...], b_ref[...])
 
     @pl.when(k == nk - 1)
     def _done():
@@ -134,8 +142,7 @@ def _addmul_epi_kernel(*refs, nk: int, prog, nextra: int):
     def _init():
         acc_ref[...] = c_ref[...].astype(jnp.float32)
 
-    acc_ref[...] += jnp.dot(a_ref[...], b_ref[...],
-                            preferred_element_type=jnp.float32)
+    acc_ref[...] += _dot_f32(a_ref[...], b_ref[...])
 
     @pl.when(k == nk - 1)
     def _done():

@@ -1,13 +1,16 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels execute in ``interpret=True`` mode — the
+On the CPU backend the kernels execute in ``interpret=True`` mode — the
 kernel body runs as traced Python, validating the exact TPU tiling logic; on
-a TPU backend the same calls compile to Mosaic.  ``use_pallas()`` is the
-single switch the rest of the framework consults.
+a TPU backend the same calls compile to Mosaic.  Any other backend is an
+error: a GPU or a misconfigured accelerator must not fall back to the
+interpreter without a word.
 """
 from __future__ import annotations
 
 import functools
+import os
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -20,7 +23,33 @@ from . import ref
 
 
 def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(f"the Pallas kernels target TPU (compiled) or CPU "
+                       f"(interpret mode); backend {backend!r} is neither")
+
+
+#: fixed, checkout-relative home of the persistent compilation cache (the
+#: directory is part of JAX's cache key, so it must not move between runs)
+COMPILE_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already keeps its cache
+    there and nothing is changed.  Otherwise the cache goes to
+    ``.jax_cache/`` at the root of the checkout.  Entry points call this
+    before their first compile; nothing calls it at import.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR))
+    return str(COMPILE_CACHE_DIR)
 
 
 def _resolve_blocks(block_m, block_n, block_k, m, n, k):
@@ -132,4 +161,4 @@ def gla(q, k, v, log_a, *, chunk: int = 128, normalize: bool = True,
 
 
 __all__ = ["matmul", "addmul", "addmul_batched", "flash_attention", "gla",
-           "ref"]
+           "ref", "enable_compile_cache"]

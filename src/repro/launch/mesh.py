@@ -6,6 +6,17 @@ state (the dry-run sets XLA_FLAGS before first jax init).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def auto_mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axis types on every axis.
+
+    Newer JAX defaults to Explicit axes, which the GSPMD-style step
+    functions (``with_sharding_constraint`` on named axes) reject.
+    """
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -17,10 +28,10 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 2, model: int = 2):
     """Tiny mesh over host devices for tests (requires
     xla_force_host_platform_device_count >= data*model in the test env)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return auto_mesh((data, model), ("data", "model"))

@@ -34,7 +34,7 @@ from ..runtime.elastic import remesh_plan
 from ..runtime.fault import FaultConfig, FleetMonitor, decide
 from ..train.steps import TrainHParams, make_train_step
 from . import specs as S
-from .mesh import make_production_mesh
+from .mesh import auto_mesh, make_production_mesh
 
 
 def build_mesh(spec: str):
@@ -44,7 +44,7 @@ def build_mesh(spec: str):
         return make_production_mesh(multi_pod=True)
     parts = [int(x) for x in spec.split("x")]
     names = ("data", "model")[:len(parts)]
-    return jax.make_mesh(tuple(parts), names)
+    return auto_mesh(parts, names)
 
 
 def main():

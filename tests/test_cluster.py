@@ -280,3 +280,50 @@ if HAVE_HYP:
         tol = 1e-4 if dtype == np.float32 else 1e-9
         np.testing.assert_allclose(out_cluster, expr.eager(),
                                    rtol=tol, atol=tol)
+
+
+def test_host_workers_never_import_jax():
+    """The cluster and elastic worker processes and the calibrate_ipc echo
+    child run on the host only: with every JAX import refused (the forked
+    children inherit the refusal), all three still run to the right
+    answer.  A process that drives the chip must still not fork them —
+    the chip belongs to one process."""
+    import os
+    import subprocess
+    import sys
+    import textwrap
+    code = textwrap.dedent("""
+        import sys
+
+        class RefuseJax:
+            def find_spec(self, name, path=None, target=None):
+                if name.split(".")[0] in ("jax", "jaxlib"):
+                    raise ImportError(f"{name} imported on a host path")
+
+        sys.meta_path.insert(0, RefuseJax())
+        import numpy as np
+        from repro.core import ClusteredMatrix as CM, CMMEngine
+        from repro.core import analytic_time_model
+        from repro.core.machine import hetero_spec
+        from repro.core.profiler import calibrate_ipc
+
+        tm = analytic_time_model()
+        spec = hetero_spec((2, 1), link_bw=1e12, latency=1e-6)
+        A, B = CM.rand(48, 48, seed=0), CM.rand(48, 48, seed=1)
+        expr = ((A @ B) - 0.5).relu() @ A.T
+        ref = expr.eager()
+        eng = CMMEngine(spec, tm, plan_cache=False)
+        for name in ("cluster", "elastic"):
+            out = eng.run(expr, tile=16, executor=name)
+            np.testing.assert_allclose(out, ref, rtol=1e-10, atol=1e-10)
+        calibrate_ipc(tm, nbytes=1 << 16, reps=1)
+        assert "jax" not in sys.modules
+        print("ok")
+    """)
+    env = dict(os.environ)
+    root = os.path.join(os.path.dirname(__file__), "..", "src")
+    env["PYTHONPATH"] = os.path.abspath(root)
+    p = subprocess.run([sys.executable, "-c", code], env=env,
+                       capture_output=True, text=True, timeout=300)
+    assert p.returncode == 0, p.stdout + p.stderr
+    assert p.stdout.strip().endswith("ok")

@@ -15,11 +15,6 @@ def test_parse_shape():
     assert parse_shape("pred[]") == (1, 1)
 
 
-@pytest.mark.xfail(
-    strict=False,
-    reason="env: this container's jax returns a list (not a dict) from "
-           "compiled.cost_analysis(); known environment failure, see "
-           "TESTING.md")
 def test_scan_trip_counts_in_flops():
     """cost_analysis misses scan trips; our analyzer must not."""
     def f(x, w):

@@ -137,3 +137,34 @@ def test_gla_kernel_bf16():
     np.testing.assert_allclose(np.asarray(y_k, np.float32),
                                np.asarray(y_r, np.float32),
                                rtol=5e-2, atol=5e-2)
+
+
+@pytest.mark.parametrize("backend,interpret", [("cpu", True), ("tpu", False),
+                                               ("gpu", None)])
+def test_interpret_mode_only_on_cpu(monkeypatch, backend, interpret):
+    """Interpret mode is for the CPU; any other non-TPU backend raises
+    instead of quietly interpreting the kernels."""
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: backend)
+    if interpret is None:
+        with pytest.raises(RuntimeError, match=backend):
+            ops._interpret_default()
+    else:
+        assert ops._interpret_default() is interpret
+
+
+def test_compile_cache_dir(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR wins and is left alone; otherwise the
+    cache goes to the checkout's fixed .jax_cache/."""
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert ops.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        want = ops.COMPILE_CACHE_DIR
+        assert want.name == ".jax_cache"
+        assert (want.parent / "src" / "repro" / "kernels" / "ops.py").exists()
+        assert ops.enable_compile_cache() == str(want)
+        assert jax.config.jax_compilation_cache_dir == str(want)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -9,35 +9,25 @@ import subprocess
 import sys
 import textwrap
 
-import pytest
-
 ROOT = os.path.join(os.path.dirname(__file__), "..")
-
-#: this container's jax does not export ``jax.shard_map``, which the
-#: sharded executors / expert-parallel MoE import in their subprocess —
-#: a known environment failure, not a code regression (see TESTING.md)
-env_no_shard_map = pytest.mark.xfail(
-    strict=False,
-    reason="env: this jax version has no jax.shard_map export; the "
-           "sharded-executor subprocess dies on import (see TESTING.md)")
 
 
 def run_sub(code: str):
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
-    p = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
+    code = "from repro.launch.mesh import auto_mesh\n" + textwrap.dedent(code)
+    p = subprocess.run([sys.executable, "-c", code],
                        capture_output=True, text=True, env=env, timeout=420)
     assert p.returncode == 0, f"STDOUT:\n{p.stdout}\nSTDERR:\n{p.stderr}"
     return p.stdout
 
 
-@env_no_shard_map
 def test_summa_2d_matches_dense():
     run_sub("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.exec.sharded import matmul_2d
-        mesh = jax.make_mesh((2, 4), ("x", "y"))
+        mesh = auto_mesh((2, 4), ("x", "y"))
         rng = np.random.default_rng(0)
         a = jnp.asarray(rng.standard_normal((64, 32)), jnp.float32)
         b = jnp.asarray(rng.standard_normal((32, 96)), jnp.float32)
@@ -48,12 +38,11 @@ def test_summa_2d_matches_dense():
     """)
 
 
-@env_no_shard_map
 def test_cannon_matches_dense():
     run_sub("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.exec.sharded import matmul_cannon
-        mesh = jax.make_mesh((2, 2), ("x", "y"))
+        mesh = auto_mesh((2, 2), ("x", "y"))
         rng = np.random.default_rng(1)
         a = jnp.asarray(rng.standard_normal((32, 32)), jnp.float32)
         b = jnp.asarray(rng.standard_normal((32, 32)), jnp.float32)
@@ -64,12 +53,11 @@ def test_cannon_matches_dense():
     """)
 
 
-@env_no_shard_map
 def test_reduce_scatter_matmul():
     run_sub("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.exec.sharded import reduce_scatter_matmul
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = auto_mesh((2, 4), ("data", "model"))
         rng = np.random.default_rng(2)
         a = jnp.asarray(rng.standard_normal((16, 32)), jnp.float32)
         b = jnp.asarray(rng.standard_normal((32, 64)), jnp.float32)
@@ -91,7 +79,7 @@ def test_train_step_on_small_mesh():
         from repro.launch import specs as S
         from repro.data.pipeline import DataConfig, make_batch
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = auto_mesh((2, 4), ("data", "model"))
         cfg = replace(get_reduced("qwen3-8b"), d_ff=192)
         plan = replace(get_plan("qwen3-8b", "train_4k"), microbatches=2)
         step, init_opt = make_train_step(cfg, plan, mesh)
@@ -113,7 +101,6 @@ def test_train_step_on_small_mesh():
     """)
 
 
-@env_no_shard_map
 def test_decode_step_on_small_mesh():
     run_sub("""
         import jax, jax.numpy as jnp, numpy as np
@@ -123,7 +110,7 @@ def test_decode_step_on_small_mesh():
         from repro.models.decode import init_cache
         from repro.train.steps import make_decode_step, make_prefill_step
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = auto_mesh((2, 4), ("data", "model"))
         cfg = get_reduced("olmoe-1b-7b")
         plan = get_plan("olmoe-1b-7b", "decode_32k")
         params = M.init_params(cfg, jax.random.PRNGKey(0))
@@ -140,7 +127,6 @@ def test_decode_step_on_small_mesh():
     """)
 
 
-@env_no_shard_map
 def test_moe_expert_parallel_matches_scatter():
     """The shard_map expert-parallel MoE (the on-mesh default) must produce
     the same outputs as the GSPMD scatter implementation."""
@@ -150,7 +136,7 @@ def test_moe_expert_parallel_matches_scatter():
         from repro.models.moe import moe_ffn
         from repro.models.moe_ep import moe_ffn_ep
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = auto_mesh((2, 4), ("data", "model"))
         rng = np.random.default_rng(0)
         B, S, D, E, F, K = 4, 8, 16, 8, 12, 2
         params = {
@@ -203,7 +189,7 @@ def test_gather_once_matches_standard_train_step():
         from repro.launch import specs as S
         from repro.data.pipeline import DataConfig, make_batch
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = auto_mesh((2, 4), ("data", "model"))
         cfg = replace(get_reduced("qwen3-8b"), d_ff=192)
         base_plan = replace(get_plan("qwen3-8b", "train_4k"),
                             microbatches=2)
