@@ -37,6 +37,7 @@ from .simulator import SimResult, simulate
 from .tiling import (TiledProgram, normalize_tile, tile_expression,
                      tile_expression_many)
 from .timemodel import CostCache, TimeModel, analytic_time_model
+from ..runtime.telemetry import Tracer
 
 
 @dataclass
@@ -74,6 +75,9 @@ class Plan:
     spill_bytes: int = 0
     #: those bytes priced through the TimeModel's spill bandwidths
     spill_seconds: float = 0.0
+    #: flight recorder: the planner's spans (a PLAN span around the call,
+    #: FUSE/CACHE/TILE/HEFT/SIM/WAVES children; node -1, the master)
+    spans: list = field(default_factory=list)
 
     @property
     def cluster_makespan(self) -> Optional[float]:
@@ -253,7 +257,18 @@ class CMMEngine:
         (tile size + per-tile home node of every resident leaf), so an
         iterative workload re-planning the same step structure hits the
         cache even though each step consumes fresh handles.
+
+        The plan carries the planner's spans (``Plan.spans``); a cache
+        hit records only FUSE and CACHE under its PLAN span.
         """
+        tracer = Tracer(node=-1)
+        with tracer.span("PLAN"):
+            plan = self._plan_many(roots, tile, fuse, fast, persist, tracer)
+        plan.spans = tracer.drain()
+        return plan
+
+    def _plan_many(self, roots, tile, fuse, fast, persist,
+                   tracer: Tracer) -> Plan:
         t0 = time.perf_counter()
         roots = list(roots)
         orig_roots = roots  # pre-optimization view, for admission re-plans
@@ -269,54 +284,60 @@ class CMMEngine:
         if fuse:
             # transposed-operand tile indexing needs a square tile on
             # ragged grids; keep explicit TRANSPOSE nodes otherwise
-            roots, report = optimize_many(roots,
-                                          fold_transpose=tile[0] == tile[1],
-                                          fuse_epilogue=self.fuse_epilogue)
+            with tracer.span("FUSE"):
+                roots, report = optimize_many(
+                    roots, fold_transpose=tile[0] == tile[1],
+                    fuse_epilogue=self.fuse_epilogue)
 
         key = None
         if self.plan_cache:
-            # the TimeModel fingerprint keys the cache too: in-place
-            # recalibration (calibrate_ipc/contention/...) must invalidate
-            # cached schedules + auto-selection verdicts, not replay them
-            key = (structural_signature_many(roots), tile, self.spec,
-                   self.cache_aware, fuse, self.timemodel.to_json(),
-                   persist_idx, residency_layout(roots))
-            hit = self._plans.get(key)
-            if hit is not None:
-                self.plan_cache_hits += 1
-                prog = hit.program.rebound(leaves_in_order_many(roots))
-                # the cached copy dropped its roots (they would pin user
-                # data); a served plan carries the CALLER's roots
-                prog.roots = list(roots)
-                prog.root = roots[0]
-                return Plan(prog, hit.schedule, hit.sim, hit.tile,
-                            time.perf_counter() - t0, spec=self.spec,
-                            fusion=report, cache_hit=True, waves=hit.waves,
-                            batched_makespan=hit.batched_makespan,
-                            _cluster_pred=hit._cluster_pred,
-                            _elastic_pred=hit._elastic_pred,
-                            peak_bytes=hit.peak_bytes,
-                            spill_bytes=hit.spill_bytes,
-                            spill_seconds=hit.spill_seconds)
-            self.plan_cache_misses += 1
+            with tracer.span("CACHE"):
+                # the TimeModel fingerprint keys the cache too: in-place
+                # recalibration (calibrate_ipc/contention/...) must invalidate
+                # cached schedules + auto-selection verdicts, not replay them
+                key = (structural_signature_many(roots), tile, self.spec,
+                       self.cache_aware, fuse, self.timemodel.to_json(),
+                       persist_idx, residency_layout(roots))
+                hit = self._plans.get(key)
+                if hit is not None:
+                    self.plan_cache_hits += 1
+                    prog = hit.program.rebound(leaves_in_order_many(roots))
+                    # the cached copy dropped its roots (they would pin user
+                    # data); a served plan carries the CALLER's roots
+                    prog.roots = list(roots)
+                    prog.root = roots[0]
+                    return Plan(prog, hit.schedule, hit.sim, hit.tile,
+                                time.perf_counter() - t0, spec=self.spec,
+                                fusion=report, cache_hit=True, waves=hit.waves,
+                                batched_makespan=hit.batched_makespan,
+                                _cluster_pred=hit._cluster_pred,
+                                _elastic_pred=hit._elastic_pred,
+                                peak_bytes=hit.peak_bytes,
+                                spill_bytes=hit.spill_bytes,
+                                spill_seconds=hit.spill_seconds)
+                self.plan_cache_misses += 1
 
-        prog = tile_expression_many(roots, tile, persist_idx)
+        with tracer.span("TILE"):
+            prog = tile_expression_many(roots, tile, persist_idx)
         # one cost object shared by scheduling, simulation and wave costing:
         # memoized on the fast path, direct (naive-baseline) otherwise
         cost = CostCache(self.timemodel, self.spec) if fast \
             else DirectCost(self.timemodel, self.spec)
-        sched = heft_schedule(prog.graph, self.spec, self.timemodel,
-                              cache_aware=self.cache_aware,
-                              fill_origin=self._fill_origins(roots),
-                              fast=fast, cost=cost,
-                              pinned=self._resident_pins(prog))
-        sim = simulate(prog.graph, sched, self.spec, self.timemodel,
-                       cost=cost)
+        with tracer.span("HEFT"):
+            sched = heft_schedule(prog.graph, self.spec, self.timemodel,
+                                  cache_aware=self.cache_aware,
+                                  fill_origin=self._fill_origins(roots),
+                                  fast=fast, cost=cost,
+                                  pinned=self._resident_pins(prog))
+        with tracer.span("SIM"):
+            sim = simulate(prog.graph, sched, self.spec, self.timemodel,
+                           cost=cost)
         from ..exec.batched import build_waves, predict_wave_makespan
-        waves = build_waves(prog.graph)
-        batched = predict_wave_makespan(prog.graph, self.spec,
-                                        self.timemodel, waves=waves,
-                                        dtypes=prog.dtypes, cost=cost)
+        with tracer.span("WAVES"):
+            waves = build_waves(prog.graph)
+            batched = predict_wave_makespan(prog.graph, self.spec,
+                                            self.timemodel, waves=waves,
+                                            dtypes=prog.dtypes, cost=cost)
         cluster_pred = None
         elastic_pred = None
         if self.spec.n_nodes > 1:
@@ -357,11 +378,10 @@ class CMMEngine:
                                        for t in prog.graph.tasks.values())
                     if tile > (1, 1) and not has_resident:
                         self.plan_shrinks += 1
-                        return self.plan_many(
+                        return self._plan_many(
                             orig_roots,
-                            tile=(max(1, tile[0] // 2),
-                                  max(1, tile[1] // 2)),
-                            fuse=fuse, fast=fast, persist=persist_idx)
+                            (max(1, tile[0] // 2), max(1, tile[1] // 2)),
+                            fuse, fast, persist_idx, tracer)
                     raise MemoryBudgetExceeded(n, floor, b)
                 spill_excess += p - b
             sim.peak_bytes.update(peaks)
@@ -490,12 +510,15 @@ class CMMEngine:
 
     # -- flight recorder ----------------------------------------------------
     def dump_trace(self, path: str, include_predicted: bool = False) -> int:
-        """Export the last run's spans as Chrome-trace JSON (load in
+        """Export the last run's planner and executor spans as
+        Chrome-trace JSON (load in
         ``chrome://tracing`` or https://ui.perfetto.dev).  With
         ``include_predicted`` the simulator's predicted timeline is
         overlaid on shifted lanes, so drift is visible in the viewer.
         Returns the number of events written."""
         spans = list(self.last_spans)
+        if self.last_plan is not None:
+            spans = self.last_plan.spans + spans
         if include_predicted and self.last_plan is not None \
                 and self.last_plan.sim is not None:
             spans += self.last_plan.sim.predicted_spans()

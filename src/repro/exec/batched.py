@@ -17,6 +17,14 @@ This backend removes it by *batching*:
    ``jax.vmap``-over-Pallas blocked GEMM (``kernels/matmul.py``) jit-cached
    per group signature.
 
+Flight recorder: one EXEC span per group call.  A device ADDMUL group's
+span holds one child span per phase — GATHER (stack and make contiguous
+on the host), H2D, KERNEL, D2H, STORE (into the arena) — each ended by
+the data it moved being ready; an ASSEMBLE span covers the result
+assembly after the last wave.  ``stats`` counts ``h2d_bytes``,
+``d2h_bytes`` and ``host_copy_bytes`` (stacked gathers, contiguity and
+dtype copies, device-path stores).
+
 Buffer arena: every group's output tiles live in ONE stacked slab
 ``(group, tm, tn)``; each tile buffer is a zero-copy view ``slab[i]``.
 When a later group's inputs are exactly a contiguous run of a slab, the
@@ -222,13 +230,27 @@ class WaveExecutor:
         #: (see TESTING.md, numerics tiers).
         self.precision = precision
         self.free_buffers = free_buffers
-        #: flight recorder: one EXEC span per batched group call (node 0,
-        #: lane 0 — waves are sequential in this process)
+        #: flight recorder: one EXEC span per batched group call, phase
+        #: spans inside device groups (node 0, lane 0 — waves are
+        #: sequential in this process)
         self.trace = trace
+        self._tracer = NULL_TRACER
         self.spans: List = []
         self.stats: Dict[str, int] = {}
 
     # -- gather helpers ----------------------------------------------------
+    def _counted(self, src: np.ndarray, dst: np.ndarray) -> np.ndarray:
+        """``dst``, counting its bytes as a host copy unless it is ``src``
+        itself (``ascontiguousarray``/``astype`` that had nothing to do)."""
+        if dst is not src:
+            self.stats["host_copy_bytes"] += dst.nbytes
+        return dst
+
+    def _stack(self, arrays) -> np.ndarray:
+        out = np.stack(arrays)
+        self.stats["host_copy_bytes"] += out.nbytes
+        return out
+
     def _gather(self, refs, buffers, arena) -> np.ndarray:
         if len(refs) == 1:
             self.stats["zero_copy_gathers"] += 1
@@ -238,7 +260,22 @@ class WaveExecutor:
             self.stats["zero_copy_gathers"] += 1
             return run
         self.stats["copied_gathers"] += 1
-        return np.stack([buffers[r] for r in refs])
+        return self._stack([buffers[r] for r in refs])
+
+    def _matmul_operands(self, tasks, buffers, arena):
+        """Stacked A and B of a matmul group, transposed as the payload's
+        flags say (a transposed operand is a strided view)."""
+        ta, tb = matmul_flags(tasks[0].payload)
+        a3 = self._gather([t.ins[0] for t in tasks], buffers, arena)
+        b3 = self._gather([t.ins[1] for t in tasks], buffers, arena)
+        if ta:
+            a3 = a3.transpose(0, 2, 1)
+        if tb:
+            b3 = b3.transpose(0, 2, 1)
+        if self.precision == "mixed":
+            a3 = self._counted(a3, a3.astype(np.float32, copy=False))
+            b3 = self._counted(b3, b3.astype(np.float32, copy=False))
+        return a3, b3
 
     # -- group kernels -----------------------------------------------------
     def _run_group(self, kind: TaskKind, tasks: List[Task], buffers, arena,
@@ -345,17 +382,11 @@ class WaveExecutor:
             buffers[t.out] = slab[i]
 
     def _run_matmul(self, kind, tasks, buffers, arena, dtypes) -> None:
-        ta, tb = matmul_flags(tasks[0].payload)
+        if kind is TaskKind.ADDMUL and self.backend == "pallas":
+            self._run_device_addmul(tasks, buffers, arena)
+            return
         epi = matmul_epilogue(tasks[0].payload)
-        a3 = self._gather([t.ins[0] for t in tasks], buffers, arena)
-        b3 = self._gather([t.ins[1] for t in tasks], buffers, arena)
-        if ta:
-            a3 = a3.transpose(0, 2, 1)
-        if tb:
-            b3 = b3.transpose(0, 2, 1)
-        if self.precision == "mixed":
-            a3 = a3.astype(np.float32, copy=False)
-            b3 = b3.astype(np.float32, copy=False)
+        a3, b3 = self._matmul_operands(tasks, buffers, arena)
 
         if kind is TaskKind.MATMUL:
             slab = np.matmul(a3, b3)
@@ -370,41 +401,6 @@ class WaveExecutor:
         # ADDMUL: C += A @ B, accumulating into the CALLOC'd tile buffers
         outs = [t.out for t in tasks]
         crun = arena.contiguous_run(outs) if len(outs) > 1 else None
-        if self.backend == "pallas":
-            from ..kernels import ops as kops
-            c3 = crun if crun is not None else \
-                np.stack([buffers[t.out] for t in tasks])
-            if epi is not None:
-                # true fused kernel: accumulator -> epilogue -> store
-                stacks = [self._gather([t.ins[j] for t in tasks],
-                                       buffers, arena)
-                          for j in range(2, len(tasks[0].ins))]
-                store_dt = self._epilogue_store_dtype()
-                slab = np.asarray(kops.addmul_batched(
-                    np.ascontiguousarray(c3), np.ascontiguousarray(a3),
-                    np.ascontiguousarray(b3),
-                    epilogue=epi,
-                    extras=[np.ascontiguousarray(s) for s in stacks],
-                    out_dtype=store_dt))
-                if store_dt is None:
-                    # strict mode: keep the wave pipeline's dtype contract
-                    # (jax may compute in f32; the plain path casts back
-                    # to the accumulator dtype the same way)
-                    slab = slab.astype(np.result_type(
-                        c3.dtype, *[s.dtype for s in stacks]), copy=False)
-                arena.register(outs, slab)
-                for i, t in enumerate(tasks):
-                    buffers[t.out] = slab[i]
-                return
-            out = np.asarray(kops.addmul_batched(
-                np.ascontiguousarray(c3), np.ascontiguousarray(a3),
-                np.ascontiguousarray(b3)), dtype=c3.dtype)
-            if crun is not None:
-                np.copyto(crun, out)
-            else:
-                for i, t in enumerate(tasks):
-                    np.copyto(buffers[t.out], out[i])
-            return
         prod = np.matmul(a3, b3)
         if crun is not None:
             crun += prod
@@ -415,8 +411,58 @@ class WaveExecutor:
             # tail of the k-chain: apply the fused epilogue over the
             # fully-accumulated C tiles in one stacked pass
             c3 = crun if crun is not None else \
-                np.stack([buffers[t.out] for t in tasks])
+                self._stack([buffers[t.out] for t in tasks])
             self._apply_epilogue(epi, tasks, c3, buffers, arena)
+
+    def _run_device_addmul(self, tasks, buffers, arena) -> None:
+        """ADDMUL group through the Pallas GEMM: C += A @ B, with a fused
+        epilogue (accumulator -> epilogue -> store) in the same launch."""
+        import jax
+        from ..kernels import ops as kops
+        tr, st = self._tracer, self.stats
+        epi = matmul_epilogue(tasks[0].payload)
+        outs = [t.out for t in tasks]
+        crun = arena.contiguous_run(outs) if len(outs) > 1 else None
+        with tr.span("GATHER"):
+            a3, b3 = self._matmul_operands(tasks, buffers, arena)
+            c3 = crun if crun is not None else \
+                self._stack([buffers[t.out] for t in tasks])
+            stacks = [] if epi is None else \
+                [self._gather([t.ins[j] for t in tasks], buffers, arena)
+                 for j in range(2, len(tasks[0].ins))]
+            host = [self._counted(x, np.ascontiguousarray(x))
+                    for x in [c3, a3, b3] + stacks]
+        with tr.span("H2D") as sp:
+            dev = jax.block_until_ready(jax.device_put(host))
+            sp.args["nbytes"] = nbytes = sum(x.nbytes for x in dev)
+        st["h2d_bytes"] += nbytes
+        store_dt = None if epi is None else self._epilogue_store_dtype()
+        with tr.span("KERNEL"):
+            res = jax.block_until_ready(kops.addmul_batched(
+                *dev[:3], epilogue=epi, extras=dev[3:], out_dtype=store_dt))
+        with tr.span("D2H") as sp:
+            res = np.asarray(res)
+            sp.args["nbytes"] = res.nbytes
+        st["d2h_bytes"] += res.nbytes
+        with tr.span("STORE"):
+            if epi is None:
+                if crun is not None:
+                    np.copyto(crun, res)
+                    st["host_copy_bytes"] += crun.nbytes
+                else:
+                    for i, t in enumerate(tasks):
+                        np.copyto(buffers[t.out], res[i])
+                        st["host_copy_bytes"] += buffers[t.out].nbytes
+                return
+            if store_dt is None:
+                # strict mode: keep the wave pipeline's dtype contract
+                # (jax may compute in f32; the plain path casts back
+                # to the accumulator dtype the same way)
+                res = self._counted(res, res.astype(np.result_type(
+                    *[x.dtype for x in [host[0]] + host[3:]]), copy=False))
+            arena.register(outs, res)
+            for i, t in enumerate(tasks):
+                buffers[t.out] = res[i]
 
     # -- driver ------------------------------------------------------------
     def execute(self, plan) -> np.ndarray:
@@ -431,7 +477,8 @@ class WaveExecutor:
         buffers: Dict[TileRef, np.ndarray] = {}
         arena = WaveArena()
         self.stats = {"zero_copy_gathers": 0, "copied_gathers": 0,
-                      "batched_calls": 0}
+                      "batched_calls": 0, "h2d_bytes": 0, "d2h_bytes": 0,
+                      "host_copy_bytes": 0}
 
         # readers per tile (+1 keeps result tiles alive for assembly and
         # persisted tiles alive for session retention — retained tiles are
@@ -452,6 +499,7 @@ class WaveExecutor:
 
         tracer = Tracer(node=0, enabled=self.trace) if self.trace \
             else NULL_TRACER
+        self._tracer = tracer
         tasks_run = 0
         for wi, wave in enumerate(waves):
             for (key, tasks) in group_wave(g, wave, dtypes):
@@ -484,18 +532,19 @@ class WaveExecutor:
         retained = 0
         outs = []
         gather_bytes = 0
-        for rs in rsets:
-            if rs.gather:
-                vals = {r: buffers[r] for r in rs.tiles}
-                gather_bytes += sum(r.bytes for r in rs.tiles)
-                outs.append(assemble(vals, rs.shape, tile, rs.uid))
-            else:
-                for r in rs.tiles:
-                    buf = buffers[r]
-                    if buf.base is not None:
-                        buf = np.ascontiguousarray(buf)
-                    residency.retain_local(rs.uid, r.i, r.j, buf)
-                    retained += 1
+        with tracer.span("ASSEMBLE"):
+            for rs in rsets:
+                if rs.gather:
+                    vals = {r: buffers[r] for r in rs.tiles}
+                    gather_bytes += sum(r.bytes for r in rs.tiles)
+                    outs.append(assemble(vals, rs.shape, tile, rs.uid))
+                else:
+                    for r in rs.tiles:
+                        buf = buffers[r]
+                        if buf.base is not None:
+                            buf = np.ascontiguousarray(buf)
+                        residency.retain_local(rs.uid, r.i, r.j, buf)
+                        retained += 1
 
         self.spans = tracer.drain()
         self.stats.update({

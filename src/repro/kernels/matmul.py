@@ -18,6 +18,10 @@ Kernels:
     applied to the float32 VMEM accumulator on the last k step, before the
     single HBM store.  This is the true-fusion leg of the matmul-epilogue
     optimization: the elementwise chain never round-trips through HBM.
+
+Each ``pallas_call`` carries a fixed ``name`` (``cmm_matmul``,
+``cmm_addmul``, ``cmm_addmul_epilogue``), which names its operation in
+the compiled program and so in a device trace, whatever wraps the call.
 """
 from __future__ import annotations
 
@@ -199,6 +203,7 @@ def addmul_epilogue(c: jax.Array, a: jax.Array, b: jax.Array, *extras,
                                        out_dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
         interpret=interpret,
+        name="cmm_addmul_epilogue",
     )(cp, ap, bp, *eps)
     return out[:m, :n]
 
@@ -245,6 +250,7 @@ def matmul(a: jax.Array, b: jax.Array, *, block_m: int = 128,
                                        out_dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
         interpret=interpret,
+        name="cmm_matmul",
     )(ap, bp)
     return out[:m, :n]
 
@@ -280,5 +286,6 @@ def addmul(c: jax.Array, a: jax.Array, b: jax.Array, *, block_m: int = 128,
                                        out_dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
         interpret=interpret,
+        name="cmm_addmul",
     )(cp, ap, bp)
     return out[:m, :n]

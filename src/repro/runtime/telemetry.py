@@ -31,6 +31,12 @@ This module is the observability substrate every executor shares:
   moment two threads race it); ``frozen_view`` hands tests/benchmarks
   the read-only dict they always consumed.
 
+* **the profiler's clock** — where JAX is already loaded, every span an
+  enabled tracer records also opens a ``jax.profiler.TraceAnnotation``
+  named ``cmm.<span name>`` over the same region, so a device trace
+  puts each idle gap down to the host phase around it.  A process that
+  has not imported JAX (cluster workers, NumPy-only paths) never does.
+
 * :func:`chrome_trace` / :func:`export_chrome_trace` — Chrome
   trace-event JSON (loads in ``chrome://tracing`` and Perfetto) with
   one process lane per node and one thread lane per worker slot, so
@@ -44,6 +50,7 @@ from __future__ import annotations
 
 import json
 import math
+import sys
 import threading
 import time
 from types import MappingProxyType
@@ -88,12 +95,30 @@ class Span:
                 f"lane={self.lane} t0={self.t0:.6f} dur={self.dur:.6f})")
 
 
+#: span name -> its profiler annotation's name, built once per name (span
+#: names are task kinds and category constants, never per-call strings)
+_ANNOTATION_NAMES: Dict[str, str] = {}
+
+
+def _profiler_annotation(name: str):
+    """A ``jax.profiler.TraceAnnotation`` named ``cmm.<name>``, or None
+    where the process has not imported JAX (it is never imported here)."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    label = _ANNOTATION_NAMES.get(name)
+    if label is None:
+        label = _ANNOTATION_NAMES.setdefault(name, "cmm." + name)
+    return jax.profiler.TraceAnnotation(label)
+
+
 class _SpanCtx:
     """Context manager recording one span on ``__exit__`` (kept as a
     tiny slotted class instead of ``contextlib`` to stay off the hot
-    path's allocation budget)."""
+    path's allocation budget).  The span's profiler annotation encloses
+    its clock readings."""
 
-    __slots__ = ("tr", "name", "cat", "lane", "args", "t0")
+    __slots__ = ("tr", "name", "cat", "lane", "args", "t0", "ann")
 
     def __init__(self, tr: "Tracer", name: str, cat: str,
                  lane: Optional[int], args: dict):
@@ -104,6 +129,9 @@ class _SpanCtx:
         self.args = args
 
     def __enter__(self):
+        self.ann = _profiler_annotation(self.name)
+        if self.ann is not None:
+            self.ann.__enter__()
         self.t0 = self.tr.clock()
         return self
 
@@ -115,6 +143,8 @@ class _SpanCtx:
                   self.t0, t1 - self.t0, self.args)
         with tr._lock:
             tr._spans.append(sp)
+        if self.ann is not None:
+            self.ann.__exit__(exc_type, exc, tb)
         return False
 
 
@@ -122,6 +152,12 @@ class _NullSpanCtx:
     """Shared no-op context for a disabled tracer (zero allocation)."""
 
     __slots__ = ()
+
+    @property
+    def args(self) -> dict:
+        """A fresh dict: what a caller writes into a disabled span's
+        payload goes nowhere."""
+        return {}
 
     def __enter__(self):
         return self

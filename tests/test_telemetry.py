@@ -352,9 +352,169 @@ def test_local_and_batched_spans():
     assert sorted(s.args["tid"] for s in ex) == sorted(
         eng.last_plan.program.graph.tasks)
     out2 = eng.run(expr, executor="batched")
-    assert eng.last_spans and all(s.args.get("batched")
-                                  for s in eng.last_spans)
+    ex = [s for s in eng.last_spans if s.cat == "EXEC"]
+    assert ex and all(s.args.get("batched") for s in ex)
+    assert [s.cat for s in eng.last_spans if s.cat != "EXEC"] == \
+        ["ASSEMBLE"]
     np.testing.assert_array_equal(out1, out2)
+
+
+# -- device path phases (batched-pallas, interpret mode on the CPU) ----------
+
+PHASES = ["GATHER", "H2D", "KERNEL", "D2H", "STORE"]
+KM_N, KM_TILE = 256, 128
+
+
+def _kmeans_arrays(seed=0):
+    rng = np.random.default_rng(seed)
+    return {k: rng.random((KM_N, KM_N), dtype=np.float32) * 2 - 1
+            for k in ("X", "Ct")}
+
+
+def _kmeans(arrays):
+    """The paper's K-Means core: relu(X @ Ct - 0.5) fused into the first
+    GEMM's last ADDMUL group, a transposed operand in the second."""
+    X = CM.from_array(arrays["X"], name="X")
+    Ct = CM.from_array(arrays["Ct"], name="Ct")
+    return ((X @ Ct) - 0.5).relu().T @ X
+
+
+def _device_run(eng, trace=True):
+    plan = eng.plan(_kmeans(_kmeans_arrays()), tile=KM_TILE)
+    out = eng.execute_plan(plan, executor="batched-pallas", trace=trace)
+    return plan, out, dict(eng.last_exec_stats), list(eng.last_spans)
+
+
+@pytest.fixture(scope="module")
+def device_runs():
+    """One traced and one untraced K-Means program on one engine (the
+    second plan is a plan-cache hit)."""
+    eng = CMMEngine(timemodel=TM)
+    return _device_run(eng), _device_run(eng, trace=False), eng
+
+
+def _inside(child, parent):
+    return parent.t0 <= child.t0 and \
+        child.t0 + child.dur <= parent.t0 + parent.dur
+
+
+def test_device_groups_record_their_phases(device_runs):
+    (_, _, stats, spans), _, _ = device_runs
+    groups = [s for s in spans if s.cat == "EXEC" and s.name == "ADDMUL"]
+    nt = KM_N // KM_TILE
+    assert len(groups) == 2 * nt          # one group per k-step per GEMM
+    for g in groups:
+        kids = [s for s in spans if s.cat in PHASES and _inside(s, g)]
+        assert [s.cat for s in sorted(kids, key=lambda s: s.t0)] == PHASES
+    assert all(any(_inside(s, g) for g in groups)
+               for s in spans if s.cat in PHASES)
+    others = [s for s in spans if s.cat == "EXEC" and s.name != "ADDMUL"]
+    assert not any(_inside(s, g) for s in spans if s.cat in PHASES
+                   for g in others)
+    (asm,) = [s for s in spans if s.cat == "ASSEMBLE"]
+    assert asm.t0 >= max(s.t0 + s.dur for s in spans if s.cat == "EXEC")
+    assert stats["batched_calls"] == len(groups) + 3  # CALLOC, FILL, TAKECOPY
+
+
+def test_device_byte_counters_match_spans_and_the_count(device_runs):
+    (_, _, stats, spans), _, _ = device_runs
+    nt, tile_bytes = KM_N // KM_TILE, KM_TILE * KM_TILE * 4
+    # per GEMM, nt groups of nt^2 tasks; each sends C, A and B tiles and
+    # receives C (the relu threshold is a constant of the epilogue)
+    assert stats["h2d_bytes"] == 2 * nt * 3 * nt ** 2 * tile_bytes
+    assert stats["d2h_bytes"] == 2 * nt * nt ** 2 * tile_bytes
+    assert stats["h2d_bytes"] == sum(s.args["nbytes"] for s in spans
+                                     if s.cat == "H2D")
+    assert stats["d2h_bytes"] == sum(s.args["nbytes"] for s in spans
+                                     if s.cat == "D2H")
+    # stacked A and B of the first GEMM; stacked, transposed-contiguous A
+    # and stacked B of the second; every store but the epilogue's
+    assert stats["host_copy_bytes"] == (7 * nt ** 3 - nt ** 2) * tile_bytes
+
+
+def test_device_path_tracing_off_is_bit_identical_and_silent(device_runs):
+    (_, on, stats_on, _), (_, off, stats_off, spans_off), _ = device_runs
+    assert spans_off == []
+    np.testing.assert_array_equal(on, off)
+    for k in ("batched_calls", "h2d_bytes", "d2h_bytes", "host_copy_bytes"):
+        assert stats_on[k] == stats_off[k]
+
+
+def test_drift_and_roofline_read_the_spans_they_read_before(device_runs):
+    (plan, _, _, spans), _, eng = device_runs
+    assert {s.cat for s in spans} > {"EXEC"}
+    exec_only = [s for s in spans if s.cat == "EXEC"]
+    from repro.core.roofline import roofline_report
+    for report in (drift_report, roofline_report):
+        assert report(spans, plan, tm=TM).as_dict() == \
+            report(exec_only, plan, tm=TM).as_dict()
+
+
+def test_planner_spans_nest_under_plan(device_runs, tmp_path):
+    (miss, _, _, _), (hit, _, _, _), eng = device_runs
+    assert not miss.cache_hit and hit.cache_hit
+    for plan, kids in ((miss, ["FUSE", "CACHE", "TILE", "HEFT", "SIM",
+                               "WAVES"]),
+                       (hit, ["FUSE", "CACHE"])):
+        (top,) = [s for s in plan.spans if s.cat == "PLAN"]
+        inner = sorted((s for s in plan.spans if s is not top),
+                       key=lambda s: s.t0)
+        assert [s.cat for s in inner] == kids
+        assert all(_inside(s, top) and s.node == -1 for s in inner)
+        assert plan.plan_seconds <= top.dur
+    n = eng.dump_trace(str(tmp_path / "trace.json"))
+    doc = json.load(open(tmp_path / "trace.json"))
+    assert n == len(doc["traceEvents"])
+    assert {e["cat"] for e in doc["traceEvents"] if e["ph"] == "X"} == \
+        {"PLAN", "FUSE", "CACHE"}            # the untraced run: plan only
+
+
+def test_spans_reach_the_profiler_as_cmm_annotations(tmp_path):
+    """Under ``jax.profiler`` each span is a ``cmm.<name>`` host
+    annotation on the profiler's clock, nested where the span is."""
+    import glob
+    import jax
+    from jax.profiler import ProfileData
+    eng = CMMEngine(timemodel=TM)
+    _device_run(eng)                                 # compile outside
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with jax.profiler.TraceAnnotation("bench.program"):
+            _device_run(eng)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    anns = [(e.name, e.start_ns, e.end_ns)
+            for p in ProfileData.from_file(path).planes
+            if p.name.startswith("/host:") for line in p.lines
+            for e in line.events
+            if e.name.startswith(("cmm.", "bench.program"))]
+    (prog,) = [a for a in anns if a[0] == "bench.program"]
+    cmm = [a for a in anns if a[0].startswith("cmm.")]
+    names = {a[0] for a in cmm}
+    assert names >= {"cmm.PLAN", "cmm.FUSE", "cmm.ADDMUL", "cmm.ASSEMBLE"} | \
+        {"cmm." + p for p in PHASES}
+    assert all(prog[1] <= s and e <= prog[2] for _, s, e in cmm)
+    for _, s, e in (a for a in cmm if a[0] == "cmm.H2D"):
+        assert any(gs <= s and e <= ge for n, gs, ge in cmm
+                   if n == "cmm.ADDMUL")
+
+
+def test_disabled_and_jax_free_tracers_annotate_nothing(monkeypatch):
+    import sys
+    from repro.runtime import telemetry
+    made = []
+    monkeypatch.setattr(telemetry, "_profiler_annotation",
+                        lambda name: made.append(name))
+    with Tracer(enabled=False).span("H2D"):
+        pass
+    assert made == []
+    with Tracer().span("H2D"):
+        pass
+    assert made == ["H2D"]
+    monkeypatch.undo()
+    monkeypatch.delitem(sys.modules, "jax")
+    assert telemetry._profiler_annotation("H2D") is None
 
 
 # -- drift report -------------------------------------------------------------

@@ -15,6 +15,8 @@ so every call passes ``interpret=False`` itself.  The topology is described
 inside a fixture, never at import: only one process at a time may load the
 TPU library.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import ml_dtypes
@@ -69,6 +71,9 @@ def test_group_call_compiles_for_v5e(one_chip, name, blocks, shapes, prog,
     args = [_stack(one_chip, *s) for s in shapes]
     compiled = fn.lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    # the kernel's own name names the device op, whatever wraps the call
+    kernel = "cmm_addmul" if prog is None else "cmm_addmul_epilogue"
+    assert re.search(rf"%{kernel}\.\d+ = ", compiled.as_text())
     (out,) = jax.tree.leaves(compiled.out_info)
     assert out.shape == (GROUP,) + shapes[0]
     assert out.dtype == (store if store is not None else jnp.float32)
