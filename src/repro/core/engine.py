@@ -208,6 +208,10 @@ class CMMEngine:
         self.last_spans: list = []
         self.last_plan: Optional[Plan] = None
         self.last_exec_stats: Dict[str, object] = {}
+        #: host staging buffers of the device wave executors that
+        #: ``execute_plan`` builds, kept from one program to the next
+        from ..exec.batched import StagingPool
+        self.staging = StagingPool()
 
     @classmethod
     def default(cls) -> "CMMEngine":
@@ -500,6 +504,8 @@ class CMMEngine:
             exec_kw["timemodel"] = self.timemodel
         if executor_obj is None:
             from ..exec import make_executor
+            if executor == "batched-pallas":
+                exec_kw.setdefault("staging", self.staging)
             executor_obj = make_executor(executor, **exec_kw)
         out = executor_obj.execute(plan)
         self.last_exec_stats = dict(executor_obj.stats)

@@ -22,8 +22,10 @@ span holds one child span per phase — GATHER (stack and make contiguous
 on the host), H2D, KERNEL, D2H, STORE (into the arena) — each ended by
 the data it moved being ready; an ASSEMBLE span covers the result
 assembly after the last wave.  ``stats`` counts ``h2d_bytes``,
-``d2h_bytes`` and ``host_copy_bytes`` (stacked gathers, contiguity and
-dtype copies, device-path stores).
+``d2h_bytes``, ``host_copy_bytes`` (stacked gathers, contiguity and
+dtype copies, device-path stores) and ``staging_allocs`` /
+``staging_reuses`` (device-path staging takes; also each GATHER span's
+``args``).
 
 Buffer arena: every group's output tiles live in ONE stacked slab
 ``(group, tm, tn)``; each tile buffer is a zero-copy view ``slab[i]``.
@@ -34,6 +36,20 @@ scratch copy.  Slabs are reference-counted like the per-task runtime: a
 slab is freed when the last reader of its last live tile finishes, so peak
 memory stays bounded by live *slabs* (wave-granular, vs tile-granular for
 the per-task executor — the throughput/peak trade-off of batching).
+
+Staging buffers (device path only): a device ADDMUL group writes every
+operand it must copy — stacked A and B, a C that is no contiguous run, the
+epilogue's extras, a transposed operand as each tile's ``.T`` in the same
+single copy — into host buffers taken from a ``StagingPool`` keyed on
+``(shape, dtype)``, and gives them back when the group ends, after STORE,
+once no device array made from them is referenced (the CPU backend's
+``device_put`` may alias a NumPy buffer).  A fresh 64 MiB buffer costs
+first-touch page faults that dwarf the copy into it, so the pool, which
+the engine hands to every device executor it builds, outlives programs.
+It holds at most what one group took at once per shape, and only the
+shapes of the last program that took any.  Arena slabs and the NumPy
+backend's stacks stay fresh allocations: they outlive their group (a slab,
+``eval_fused``'s result may alias its inputs).
 
 Numerics: the NumPy backend is bit-identical to ``LocalExecutor`` — a 3-D
 ``np.matmul`` issues the same BLAS GEMM per slice as the per-task ``@``,
@@ -48,7 +64,8 @@ and picks the cheaper strategy per plan.
 """
 from __future__ import annotations
 
-from typing import Dict, List, Optional, Sequence, Tuple
+import threading
+from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -204,6 +221,64 @@ class WaveArena:
         return False
 
 
+def stack_into(buf: np.ndarray, tiles: Sequence[np.ndarray],
+               transpose: bool = False) -> np.ndarray:
+    """Stack 2-D ``tiles`` into ``buf`` in one copy — each tile's ``.T``
+    where ``transpose``, casting to ``buf``'s dtype; returns ``buf``.
+
+    Bitwise equal to ``np.stack(tiles)`` (``.transpose(0, 2, 1)`` made
+    contiguous, ``.astype(buf.dtype)``), without their fresh buffers.
+    """
+    for slot, t in zip(buf, tiles):
+        np.copyto(slot, t.T if transpose else t, casting="same_kind")
+    return buf
+
+
+class StagingPool:
+    """Free host staging buffers of the device path, keyed on
+    ``(shape, dtype)``; thread-safe.
+
+    Groups run one at a time, take their buffers and give them all back,
+    so each shape's free list grows to the most buffers one group held at
+    once, and no further.  ``retain`` drops the free buffers of shapes a
+    program no longer uses.
+    """
+
+    def __init__(self):
+        self._free: Dict[tuple, List[np.ndarray]] = {}
+        self._lock = threading.Lock()
+
+    def take(self, shape: tuple, dtype) -> Tuple[np.ndarray, bool]:
+        """A buffer of ``shape`` and ``dtype``, uninitialised; and whether
+        it was reused rather than allocated."""
+        key = (tuple(shape), np.dtype(dtype))
+        with self._lock:
+            free = self._free.get(key)
+            if free:
+                return free.pop(), True
+        return np.empty(key[0], key[1]), False
+
+    def give(self, bufs: Iterable[np.ndarray]) -> None:
+        """Return buffers taken from this pool; nothing may refer to them
+        or to device arrays made from them any more."""
+        with self._lock:
+            for b in bufs:
+                self._free.setdefault((b.shape, b.dtype), []).append(b)
+
+    def retain(self, keys: Iterable[tuple]) -> None:
+        """Drop the free buffers of every ``(shape, dtype)`` not in
+        ``keys``."""
+        keys = set(keys)
+        with self._lock:
+            self._free = {k: v for k, v in self._free.items() if k in keys}
+
+    def held_bytes(self) -> Dict[tuple, int]:
+        """Bytes of free buffers per ``(shape, dtype)``."""
+        with self._lock:
+            return {k: sum(b.nbytes for b in v)
+                    for k, v in self._free.items()}
+
+
 class WaveExecutor:
     """Executes a planned tiled program wave-by-wave with batched kernels.
 
@@ -213,10 +288,14 @@ class WaveExecutor:
     mode on CPU, compiled on TPU), jit-cached per group signature.
 
     ``free_buffers=False`` keeps every slab alive (debugging / benchmarks).
+    ``staging`` is the device path's ``StagingPool``; the engine passes its
+    own so that the buffers outlive this executor, otherwise the executor
+    makes one.
     """
 
     def __init__(self, backend: str = "numpy", free_buffers: bool = True,
-                 trace: bool = True, precision: str = "strict"):
+                 trace: bool = True, precision: str = "strict",
+                 staging: Optional[StagingPool] = None):
         if backend not in ("numpy", "pallas"):
             raise ValueError(f"unknown wave backend {backend!r}")
         if precision not in ("strict", "mixed"):
@@ -230,6 +309,9 @@ class WaveExecutor:
         #: (see TESTING.md, numerics tiers).
         self.precision = precision
         self.free_buffers = free_buffers
+        self.staging = staging if staging is not None else StagingPool()
+        #: staging keys taken in the current run
+        self._staged_keys: set = set()
         #: flight recorder: one EXEC span per batched group call, phase
         #: spans inside device groups (node 0, lane 0 — waves are
         #: sequential in this process)
@@ -414,6 +496,30 @@ class WaveExecutor:
                 self._stack([buffers[t.out] for t in tasks])
             self._apply_epilogue(epi, tasks, c3, buffers, arena)
 
+    def _device_operand(self, refs, buffers, arena, taken, transpose=False,
+                        dtype=None) -> np.ndarray:
+        """One stacked operand of a device group, C-contiguous and of
+        ``dtype`` (default: the tiles'): a zero-copy view where the tiles
+        already lie so, else one copy into a staging buffer, which is
+        appended to ``taken`` (each tile's ``.T`` where ``transpose``)."""
+        tiles = [buffers[r] for r in refs]
+        dtype = tiles[0].dtype if dtype is None else np.dtype(dtype)
+        if not transpose:
+            run = tiles[0][None] if len(refs) == 1 else \
+                arena.contiguous_run(refs)
+            if run is not None and run.shape[0] == len(refs) and \
+                    run.flags.c_contiguous and run.dtype == dtype:
+                self.stats["zero_copy_gathers"] += 1
+                return run
+        self.stats["copied_gathers"] += 1
+        shape = tiles[0].shape[::-1] if transpose else tiles[0].shape
+        buf, reused = self.staging.take((len(tiles),) + shape, dtype)
+        taken.append(buf)
+        self._staged_keys.add((buf.shape, buf.dtype))
+        self.stats["staging_reuses" if reused else "staging_allocs"] += 1
+        self.stats["host_copy_bytes"] += buf.nbytes
+        return stack_into(buf, tiles, transpose)
+
     def _run_device_addmul(self, tasks, buffers, arena) -> None:
         """ADDMUL group through the Pallas GEMM: C += A @ B, with a fused
         epilogue (accumulator -> epilogue -> store) in the same launch."""
@@ -421,17 +527,24 @@ class WaveExecutor:
         from ..kernels import ops as kops
         tr, st = self._tracer, self.stats
         epi = matmul_epilogue(tasks[0].payload)
+        ta, tb = matmul_flags(tasks[0].payload)
+        ab_dt = np.float32 if self.precision == "mixed" else None
         outs = [t.out for t in tasks]
         crun = arena.contiguous_run(outs) if len(outs) > 1 else None
-        with tr.span("GATHER"):
-            a3, b3 = self._matmul_operands(tasks, buffers, arena)
-            c3 = crun if crun is not None else \
-                self._stack([buffers[t.out] for t in tasks])
-            stacks = [] if epi is None else \
-                [self._gather([t.ins[j] for t in tasks], buffers, arena)
-                 for j in range(2, len(tasks[0].ins))]
-            host = [self._counted(x, np.ascontiguousarray(x))
-                    for x in [c3, a3, b3] + stacks]
+        taken: List[np.ndarray] = []
+
+        def operand(j, transpose=False, dtype=None):
+            return self._device_operand([t.ins[j] for t in tasks], buffers,
+                                        arena, taken, transpose, dtype)
+
+        with tr.span("GATHER") as sp:
+            allocs, reuses = st["staging_allocs"], st["staging_reuses"]
+            host = [self._device_operand(outs, buffers, arena, taken),
+                    operand(0, ta, ab_dt), operand(1, tb, ab_dt)]
+            if epi is not None:
+                host += [operand(j) for j in range(2, len(tasks[0].ins))]
+            sp.args["staging_allocs"] = st["staging_allocs"] - allocs
+            sp.args["staging_reuses"] = st["staging_reuses"] - reuses
         with tr.span("H2D") as sp:
             dev = jax.block_until_ready(jax.device_put(host))
             sp.args["nbytes"] = nbytes = sum(x.nbytes for x in dev)
@@ -453,16 +566,22 @@ class WaveExecutor:
                     for i, t in enumerate(tasks):
                         np.copyto(buffers[t.out], res[i])
                         st["host_copy_bytes"] += buffers[t.out].nbytes
-                return
-            if store_dt is None:
-                # strict mode: keep the wave pipeline's dtype contract
-                # (jax may compute in f32; the plain path casts back
-                # to the accumulator dtype the same way)
-                res = self._counted(res, res.astype(np.result_type(
-                    *[x.dtype for x in [host[0]] + host[3:]]), copy=False))
-            arena.register(outs, res)
-            for i, t in enumerate(tasks):
-                buffers[t.out] = res[i]
+            else:
+                if store_dt is None:
+                    # strict mode: keep the wave pipeline's dtype contract
+                    # (jax may compute in f32; the plain path casts back
+                    # to the accumulator dtype the same way)
+                    res = self._counted(res, res.astype(np.result_type(
+                        *[x.dtype for x in [host[0]] + host[3:]]),
+                        copy=False))
+                arena.register(outs, res)
+                for i, t in enumerate(tasks):
+                    buffers[t.out] = res[i]
+        # nothing refers to the device arrays made from the staging
+        # buffers any more, so the next group may overwrite them (after an
+        # error they are dropped instead: a transfer may still hold them)
+        del dev
+        self.staging.give(taken)
 
     # -- driver ------------------------------------------------------------
     def execute(self, plan) -> np.ndarray:
@@ -478,7 +597,9 @@ class WaveExecutor:
         arena = WaveArena()
         self.stats = {"zero_copy_gathers": 0, "copied_gathers": 0,
                       "batched_calls": 0, "h2d_bytes": 0, "d2h_bytes": 0,
-                      "host_copy_bytes": 0}
+                      "host_copy_bytes": 0, "staging_allocs": 0,
+                      "staging_reuses": 0}
+        self._staged_keys = set()
 
         # readers per tile (+1 keeps result tiles alive for assembly and
         # persisted tiles alive for session retention — retained tiles are
@@ -546,6 +667,8 @@ class WaveExecutor:
                         residency.retain_local(rs.uid, r.i, r.j, buf)
                         retained += 1
 
+        if self._staged_keys:
+            self.staging.retain(self._staged_keys)
         self.spans = tracer.drain()
         self.stats.update({
             "peak_buffer_bytes": arena.peak_bytes,

@@ -6,8 +6,8 @@ from repro.core import (ClusteredMatrix as CM, CMMEngine, TimeModel,
                         analytic_time_model, c5_9xlarge)
 from repro.core.graph import TaskGraph, TaskKind, TileRef
 from repro.core.machine import local_spec
-from repro.exec.batched import (WaveExecutor, build_waves,
-                                predict_wave_makespan)
+from repro.exec.batched import (StagingPool, WaveExecutor, build_waves,
+                                predict_wave_makespan, stack_into)
 from repro.exec.local import LocalExecutor
 
 TM = analytic_time_model()
@@ -213,6 +213,101 @@ def test_batched_pallas_backend_matches_at_tolerance():
     eng = CMMEngine(local_spec(1), TM, plan_cache=False)
     out = eng.run(expr, tile=16, executor="batched-pallas")
     np.testing.assert_allclose(out, expr.eager(), rtol=1e-4, atol=1e-4)
+
+
+# -- device-path staging buffers --------------------------------------------
+
+@pytest.mark.parametrize("tm,tn", [(24, 40), (40, 24), (32, 32)])
+@pytest.mark.parametrize("transpose", [True, False])
+def test_stack_into_a_reused_buffer_is_one_bitwise_copy(tm, tn, transpose):
+    """The device gather's one copy into a reused staging buffer equals the
+    stack, transpose view and ``ascontiguousarray`` it replaces, for
+    contiguous and strided tiles alike."""
+    rng = np.random.default_rng(7)
+    whole = rng.standard_normal((3 * tm, 2 * tn)).astype(np.float32)
+    tiles = [whole[i * tm:(i + 1) * tm, j * tn:(j + 1) * tn]   # strided
+             for i in range(3) for j in range(2)]
+    tiles += [rng.standard_normal((tm, tn)).astype(np.float32)]
+    shape = (len(tiles),) + ((tn, tm) if transpose else (tm, tn))
+    pool = StagingPool()
+    buf, reused = pool.take(shape, np.float32)
+    assert not reused
+    buf[...] = np.nan                                # dirty it, give back
+    pool.give([buf])
+    again, reused = pool.take(shape, np.float32)
+    assert reused and again is buf
+    out = stack_into(again, tiles, transpose)
+    ref = np.stack(tiles)
+    if transpose:
+        ref = np.ascontiguousarray(ref.transpose(0, 2, 1))
+    assert out is buf and out.flags.c_contiguous
+    assert out.shape == ref.shape and out.tobytes() == ref.tobytes()
+
+
+def test_stack_into_casts_like_astype():
+    """Mixed precision stages float64 or bfloat16 tiles straight into a
+    float32 buffer: the bytes ``astype`` of the stack would give."""
+    import ml_dtypes
+    rng = np.random.default_rng(3)
+    for dt in (np.float64, ml_dtypes.bfloat16):
+        tiles = [rng.standard_normal((16, 24)).astype(dt) for _ in range(4)]
+        out = stack_into(np.empty((4, 24, 16), np.float32), tiles, True)
+        ref = np.ascontiguousarray(
+            np.stack(tiles).transpose(0, 2, 1)).astype(np.float32)
+        assert out.tobytes() == ref.tobytes()
+
+
+def test_staging_pool_keys_bounds_and_retain():
+    pool = StagingPool()
+    a, _ = pool.take((2, 8, 8), np.float32)
+    b, _ = pool.take((2, 8, 8), np.float32)
+    c, _ = pool.take((2, 8, 8), np.float64)
+    pool.give([a, b, c])
+    held = pool.held_bytes()
+    assert held == {((2, 8, 8), np.dtype(np.float32)): 2 * a.nbytes,
+                    ((2, 8, 8), np.dtype(np.float64)): c.nbytes}
+    # each shape's free list is what one taker held at once, no more
+    for _ in range(3):
+        x, reused = pool.take((2, 8, 8), np.float32)
+        assert reused
+        pool.give([x])
+    assert pool.held_bytes() == held
+    assert pool.take((2, 8, 4), np.float32)[1] is False   # another shape
+    pool.retain({((2, 8, 8), np.dtype(np.float64))})
+    assert pool.held_bytes() == {((2, 8, 8), np.dtype(np.float64)):
+                                 c.nbytes}
+
+
+def test_staging_pool_is_thread_safe():
+    """Many threads taking and giving back never share a buffer."""
+    import sys
+    import threading
+    pool, errors = StagingPool(), []
+
+    def worker(k):
+        try:
+            for _ in range(200):
+                buf, _ = pool.take((4,), np.int64)
+                buf[:] = k
+                if not (buf == k).all():     # pragma: no cover
+                    errors.append(k)
+                pool.give([buf])
+        except Exception as e:               # pragma: no cover
+            errors.append(e)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=worker, args=(k,))
+                   for k in range(16)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=30)
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in threads) and not errors
+    assert pool.held_bytes()[((4,), np.dtype(np.int64))] <= 16 * 32
 
 
 # -- per-task executor accounting (satellite fix) ---------------------------

@@ -379,8 +379,8 @@ def _kmeans(arrays):
     return ((X @ Ct) - 0.5).relu().T @ X
 
 
-def _device_run(eng, trace=True):
-    plan = eng.plan(_kmeans(_kmeans_arrays()), tile=KM_TILE)
+def _device_run(eng, trace=True, seed=0):
+    plan = eng.plan(_kmeans(_kmeans_arrays(seed)), tile=KM_TILE)
     out = eng.execute_plan(plan, executor="batched-pallas", trace=trace)
     return plan, out, dict(eng.last_exec_stats), list(eng.last_spans)
 
@@ -427,9 +427,9 @@ def test_device_byte_counters_match_spans_and_the_count(device_runs):
                                      if s.cat == "H2D")
     assert stats["d2h_bytes"] == sum(s.args["nbytes"] for s in spans
                                      if s.cat == "D2H")
-    # stacked A and B of the first GEMM; stacked, transposed-contiguous A
-    # and stacked B of the second; every store but the epilogue's
-    assert stats["host_copy_bytes"] == (7 * nt ** 3 - nt ** 2) * tile_bytes
+    # stacked A and B of the first GEMM; A stacked as its tiles' .T (one
+    # copy) and stacked B of the second; every store but the epilogue's
+    assert stats["host_copy_bytes"] == (6 * nt ** 3 - nt ** 2) * tile_bytes
 
 
 def test_device_path_tracing_off_is_bit_identical_and_silent(device_runs):
@@ -438,6 +438,51 @@ def test_device_path_tracing_off_is_bit_identical_and_silent(device_runs):
     np.testing.assert_array_equal(on, off)
     for k in ("batched_calls", "h2d_bytes", "d2h_bytes", "host_copy_bytes"):
         assert stats_on[k] == stats_off[k]
+
+
+def test_staging_buffers_are_reused_within_and_across_programs(device_runs):
+    """Each ADDMUL group stages A and B (a C slab run is sent as it is):
+    the engine's first program allocates one group's two buffers and
+    reuses them in every later group; a later program allocates none."""
+    (_, _, first, spans), (_, _, second, _), eng = device_runs
+    nt, tile_bytes = KM_N // KM_TILE, KM_TILE * KM_TILE * 4
+    assert (first["staging_allocs"], first["staging_reuses"]) == \
+        (2, 4 * nt - 2)
+    assert (second["staging_allocs"], second["staging_reuses"]) == \
+        (0, 4 * nt)
+    gathers = [s for s in spans if s.cat == "GATHER"]
+    assert len(gathers) == 2 * nt
+    for k in ("staging_allocs", "staging_reuses"):
+        assert sum(s.args[k] for s in gathers) == first[k]
+    # the pool holds what one group took at once, of the one shape in use
+    assert eng.staging.held_bytes() == {
+        ((nt ** 2, KM_TILE, KM_TILE), np.dtype(np.float32)):
+            2 * nt ** 2 * tile_bytes}
+
+
+def test_a_dirty_staging_pool_changes_no_output(device_runs):
+    """A program on an engine whose pool another program, on other data,
+    left dirty is bitwise the same program on a fresh engine."""
+    (_, fresh, _, _), _, _ = device_runs
+    eng = CMMEngine(timemodel=TM)
+    _, other, _, _ = _device_run(eng, seed=1)
+    _, out, stats, _ = _device_run(eng)
+    assert stats["staging_allocs"] == 0
+    assert not np.array_equal(other, out)
+    np.testing.assert_array_equal(out, fresh)
+
+
+def test_numpy_wave_backend_uses_no_staging():
+    """The NumPy ``batched`` backend keeps its own stacks: it takes nothing
+    from the engine's pool and stays bit-identical to ``local``."""
+    eng = CMMEngine(timemodel=TM)
+    expr = _kmeans(_kmeans_arrays())
+    out = eng.run(expr, tile=KM_TILE, executor="batched")
+    stats = eng.last_exec_stats
+    assert (stats["staging_allocs"], stats["staging_reuses"]) == (0, 0)
+    assert eng.staging.held_bytes() == {}
+    np.testing.assert_array_equal(
+        out, eng.run(expr, tile=KM_TILE, executor="local"))
 
 
 def test_drift_and_roofline_read_the_spans_they_read_before(device_runs):
