@@ -87,6 +87,16 @@ def resolve(spec: dict, cell: str) -> dict:
     }
 
 
+def make_env(files: dict, seed: int,
+             overrides: Optional[dict] = None) -> dict:
+    """What a loop is built from: the configuration (with ``overrides``),
+    the traffic mix, the seed and the program's and reference's modules."""
+    return {"cfg": dict(files["cfg"], **(overrides or {})),
+            "traffic": files["traffic"], "seed": seed,
+            "prog_mod": importlib.import_module(files["program"]),
+            "ref_mod": importlib.import_module(files["reference"])}
+
+
 def configure_jax():
     """Keep JAX's persistent compilation cache where
     ``JAX_COMPILATION_CACHE_DIR`` says, else at ``.jax_cache/`` in the
@@ -248,23 +258,29 @@ class Session(Loop):
         self.session = CMMSession(self.engine, executor=self.cfg["executor"],
                                   tile=self.cfg["tile"],
                                   precision=self.cfg["precision"])
-        self.acc = {"plan_s": 0.0, "group_calls": 0}
+        self.acc = self.zero()
         self.resident = {k: self.persist(CM.from_array(a, name=k))
                          for k, a in self.state.items()}
 
     def persist(self, expr):
         with self.ann("bench.persist"):
             h = self.session.persist(expr)
+        st = self.engine.last_exec_stats
         self.acc["plan_s"] += self.engine.last_plan.plan_seconds
-        self.acc["group_calls"] += self.engine.last_exec_stats["batched_calls"]
+        self.acc["group_calls"] += st["batched_calls"]
+        self.acc["xfer_bytes"] += st["h2d_bytes"] + st["d2h_bytes"]
         return h
+
+    @staticmethod
+    def zero() -> dict:
+        return {"plan_s": 0.0, "group_calls": 0, "xfer_bytes": 0}
 
     def gather(self, h):
         with self.ann("bench.gather"):
             return h.to_numpy()
 
     def one(self, r):
-        self.acc = {"plan_s": 0.0, "group_calls": 0}
+        self.acc = self.zero()
         t0 = time.perf_counter()
         with self.ann("bench.query"):
             arrays = self.inputs(r)
@@ -320,12 +336,8 @@ def run_cell(cell: str, seed: int, seconds: float, trace: bool,
     import jax
     spec = spec if spec is not None else load_json(ROOT / "BENCHMARK.json")
     files = resolve(spec, cell)
-    cfg = dict(files["cfg"], **(overrides or {}))
-    traffic = files["traffic"]
-    loop_kind = traffic["loop"]
-    env = {"cfg": cfg, "traffic": traffic, "seed": seed,
-           "prog_mod": importlib.import_module(files["program"]),
-           "ref_mod": importlib.import_module(files["reference"])}
+    env = make_env(files, seed, overrides)
+    loop_kind = files["traffic"]["loop"]
     compiles = CompileCounter()
     jax.monitoring.register_event_duration_secs_listener(compiles)
     jax.monitoring.register_event_listener(compiles.hit)

@@ -1,15 +1,20 @@
-"""Where a one-shot program's time goes, by the program's own spans.
+"""Where a unit's time goes, by the program's own spans.
 
     python3 bench/phases.py --workload <cell> --seed <n> --units <k> \
-        [--untraced-units <u>] [--profile-units <p>] [--out <file>]
+        [--untraced-units <u>] [--profile-units <p>] [--set key=value] \
+        [--out <file>]
 
-from the root of a checkout, on the chip.  It runs the cell's one-shot
-loop as ``run.py`` does (set-up, one warm-up unit), then ``k`` units with
-the wave executor's tracer on (the default) and ``u`` (default ``k``) with
-it off, alternating while both last, then ``p`` units with the tracer on
-under the JAX profiler.  Every traced unit is split by the spans that the
-planner (``Plan.spans``) and the wave executor (``engine.last_spans``)
-recorded:
+from the root of a checkout, on the chip.  It runs the cell's loop as
+``run.py`` does (set-up, one warm-up unit), then ``k`` units with the wave
+executor's tracer on (the default) and, in a one-shot cell, ``u`` (default
+``k``) with it off, alternating while both last, then ``p`` units with the
+tracer on under the JAX profiler.  A session keeps its executor's tracer
+on, as in ``run.py``, so a session cell runs traced units only.  Every
+traced unit is split by the spans that the planner (``Plan.spans``) and
+the wave executor (``engine.last_spans``) recorded, and by the executor's
+counters (``engine.last_exec_stats``); a session query takes together
+those of all its persists.  ``--set`` replaces a size of the
+configuration (a JSON value), to probe other sizes than the cell's:
 
 * ``planner_ms``: the ``PLAN`` span;
 * ``xfer_ms``: the ``H2D`` and ``D2H`` spans;
@@ -23,19 +28,14 @@ recorded:
 * ``spans``: ``[category, name, wave, start_ms, ms]`` of every span, the
   start counted from the unit's first span.
 
-The profiled units' trace is reduced by ``trace_reduce.reduce_trace`` with
-the program's ``cmm.`` annotations beside the benchmark's ``bench.`` ones,
-so its idle time goes to the innermost of either.  One JSON object goes to
-``--out`` (default ``chiprun_out/phases.json``) and a summary to standard
-output.  Nothing here is read by ``run.py``.
+The profiled units' trace is reduced by ``trace_reduce.reduce_trace``, so
+its idle time goes to the innermost ``cmm.`` or ``bench.`` annotation.
+One JSON object goes to ``--out`` and a summary to standard output.  Nothing here is read by ``run.py``.
 """
 from __future__ import annotations
 
 import argparse
-import glob
-import importlib
 import json
-import os
 import shutil
 import statistics
 import sys
@@ -47,11 +47,12 @@ ROOT = Path(__file__).resolve().parents[1]
 if __name__ == "__main__":
     sys.path[0:1] = [str(ROOT), str(ROOT / "src")]
 
-from bench.harness import (OneShot, UNITS, WARMUP, load_json,  # noqa: E402
+from bench.harness import (OneShot, Session, UNITS,  # noqa: E402
+                           UNIT_ANNOTATION, WARMUP, load_json, make_env,
                            resolve, rng)
 
-PROGRAM_PREFIX = "cmm."
 HOST_PHASES = ("GATHER", "STORE", "ASSEMBLE")
+STATS = ("h2d_bytes", "d2h_bytes", "host_copy_bytes")
 
 
 def _inside(child, parent) -> bool:
@@ -114,16 +115,38 @@ class PhasedOneShot(OneShot):
         return rec, out
 
 
-def host_annotations(trace_dir: str, prefixes=("bench.", PROGRAM_PREFIX)):
-    """``(name, start_ns, end_ns)`` of the host annotations under the
-    given name prefixes in the newest trace under ``trace_dir``."""
-    from jax.profiler import ProfileData
-    files = sorted(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
-                             recursive=True), key=os.path.getmtime)
-    pd = ProfileData.from_file(files[-1])
-    return [(e.name, e.start_ns, e.end_ns) for p in pd.planes
-            if p.name.startswith("/host:") for line in p.lines
-            for e in line.events if e.name.startswith(prefixes)]
+class PhasedSession(Session):
+    """The cell's session loop, each query's phases in its record: the
+    spans and counters of the query's persists taken together."""
+
+    def __init__(self, env: dict):
+        self.parts = self._empty()
+        super().__init__(env)
+
+    @staticmethod
+    def _empty() -> dict:
+        return {"plan": [], "exec": [], "stats": dict.fromkeys(STATS, 0)}
+
+    def persist(self, expr):
+        h = super().persist(expr)
+        e = self.engine
+        self.parts["plan"] += e.last_plan.spans
+        self.parts["exec"] += e.last_spans
+        for k in STATS:
+            self.parts["stats"][k] += e.last_exec_stats[k]
+        return h
+
+    def one(self, r):
+        self.parts = self._empty()
+        rec, out = super().one(r)
+        rec.update(traced=True, profiled=self.tracing)
+        p = self.parts
+        rec.update(unit_phases(p["plan"], p["exec"], p["stats"],
+                               rec["dur_s"]))
+        return rec, out
+
+
+PHASED = {"oneshot": PhasedOneShot, "session": PhasedSession}
 
 
 def _median_of(records: List[dict], key: str) -> Optional[float]:
@@ -133,8 +156,8 @@ def _median_of(records: List[dict], key: str) -> Optional[float]:
 
 def summarize(records: List[dict]) -> dict:
     """Medians of the phase numbers over traced, unprofiled units; the
-    slowest such unit beside the median one; and ``program_s`` with the
-    executor's tracer on, off and under the profiler."""
+    slowest such unit beside the median one; and the median unit's
+    seconds with the executor's tracer on, off and under the profiler."""
     plain = [r for r in records if r["traced"] and not r["profiled"]]
     keys = ("dur_s", "planner_ms", "xfer_ms", "kernel_ms", "host_ms",
             "other_ms", "xfer_mib", "host_copy_mib")
@@ -146,31 +169,31 @@ def summarize(records: List[dict]) -> dict:
     for name, sel in (("traced", plain),
                       ("untraced", [r for r in records if not r["traced"]]),
                       ("profiled", [r for r in records if r["profiled"]])):
-        out[f"program_s.{name}"] = _median_of(sel, "dur_s")
+        out[f"unit_s.{name}"] = _median_of(sel, "dur_s")
     return out
 
 
-def run_phases(cell: str, seed: int, units: int, untraced_units: int,
-               profile_units: int, *, spec: Optional[dict] = None,
-               overrides: Optional[dict] = None,
+def run_phases(cell: str, seed: int, units: int,
+               untraced_units: Optional[int], profile_units: int, *,
+               spec: Optional[dict] = None, overrides: Optional[dict] = None,
                trace_dir: Optional[Path] = None, n_devices: int = 1,
                log=print) -> dict:
     """Run ``units`` traced and ``untraced_units`` untraced units of a
-    one-shot cell, alternating while both last, then ``profile_units``
-    profiled ones; returns the unit records, their summary and (with
-    ``profile_units``) the reduction of the profiled units' trace, which
-    is empty where the trace holds no device plane."""
+    cell, alternating while both last, then ``profile_units`` profiled
+    ones (``untraced_units`` None: as many as ``units`` in a one-shot
+    cell, none in a session); returns the unit records, their summary
+    and (with ``profile_units``) the reduction of the profiled units'
+    trace, which is empty where the trace holds no device plane."""
     import jax
     from bench.trace_reduce import load_events, reduce_trace
     spec = spec if spec is not None else load_json(ROOT / "BENCHMARK.json")
     files = resolve(spec, cell)
-    if files["traffic"]["loop"] != "oneshot":
-        raise ValueError(f"{cell} is not a one-shot cell")
-    env = {"cfg": dict(files["cfg"], **(overrides or {})),
-           "traffic": files["traffic"], "seed": seed,
-           "prog_mod": importlib.import_module(files["program"]),
-           "ref_mod": importlib.import_module(files["reference"])}
-    lp = PhasedOneShot(env)
+    loop = files["traffic"]["loop"]
+    if untraced_units is None:
+        untraced_units = units if loop == "oneshot" else 0
+    if loop == "session" and untraced_units:
+        raise ValueError(f"{cell}: a session runs traced units only")
+    lp = PHASED[loop](make_env(files, seed, overrides))
     lp.one(rng(seed, WARMUP))
     order = [True, False] * min(units, untraced_units) + \
         [units > untraced_units] * abs(units - untraced_units)
@@ -190,9 +213,8 @@ def run_phases(cell: str, seed: int, units: int, untraced_units: int,
         finally:
             jax.profiler.stop_trace()
             lp.tracing = False
-        ops, _ = load_events(trace_dir, n_devices)
-        anns = host_annotations(trace_dir)
-        reduction = reduce_trace(ops, anns, "bench.program", top=40)
+        ops, anns = load_events(trace_dir, n_devices)
+        reduction = reduce_trace(ops, anns, UNIT_ANNOTATION[loop], top=40)
         reduction["annotations"] = sorted({a[0] for a in anns})
     lp.close()
     summary = summarize(records)
@@ -201,7 +223,8 @@ def run_phases(cell: str, seed: int, units: int, untraced_units: int,
             r = summary[which]
             log(f"{which}: {r['dur_s']!r} s, phases ms "
                 f"{json.dumps(r['phase_ms'])}")
-    return {"workload": cell, "seed": seed, "records": records,
+    return {"workload": cell, "seed": seed,
+            "overrides": overrides or {}, "records": records,
             "summary": summary, "reduction": reduction}
 
 
@@ -212,6 +235,9 @@ def main() -> None:
     ap.add_argument("--units", type=int, required=True)
     ap.add_argument("--untraced-units", type=int, default=None)
     ap.add_argument("--profile-units", type=int, default=3)
+    ap.add_argument("--set", action="append", default=[],
+                    metavar="KEY=VALUE",
+                    help="replace a size of the configuration")
     ap.add_argument("--out", default=str(ROOT / "chiprun_out" /
                                          "phases.json"))
     args = ap.parse_args()
@@ -220,10 +246,11 @@ def main() -> None:
     jax = configure_jax()
     if jax.devices()[0].platform != "tpu":
         sys.exit("phases: JAX found no TPU")
-    untraced = args.units if args.untraced_units is None \
-        else args.untraced_units
-    res = run_phases(args.workload, args.seed, args.units, untraced,
-                     args.profile_units)
+    overrides = {k: json.loads(v) for k, v in
+                 (kv.split("=", 1) for kv in args.set)}
+    res = run_phases(args.workload, args.seed, args.units,
+                     args.untraced_units, args.profile_units,
+                     overrides=overrides)
     Path(args.out).parent.mkdir(parents=True, exist_ok=True)
     with open(args.out, "w") as f:
         json.dump(res, f)

@@ -1,10 +1,11 @@
 """The program's spans in the benchmark's terms: the trace reduction with
 the program's ``cmm.`` annotations beside the benchmark's own (made-up
-events), and ``phases.py`` on the tiny K-Means cell on the CPU."""
+events), and ``phases.py`` on the tiny K-Means and Markov cells on the
+CPU."""
 import pytest
 
-from bench.phases import host_annotations, run_phases, unit_phases
-from bench.trace_reduce import reduce_trace
+from bench.phases import run_phases, unit_phases
+from bench.trace_reduce import load_events, reduce_trace
 
 MS = 1_000_000  # ns
 TINY = {"n": 256, "tile": 128}
@@ -70,8 +71,9 @@ def test_phases_of_the_tiny_kmeans_cell(tiny_phases):
         # 2 GEMMs of nt groups (k-steps) of nt^2 tile tasks; each group
         # sends C, A and B and receives C: 32 n^2 (n / tile) bytes
         assert r["xfer_mib"] * 2 ** 20 == 32 * n * n * nt
+        # each operand tile stacked once, GEMM 2's transposed A too
         assert r["host_copy_mib"] * 2 ** 20 == \
-            (7 * nt ** 3 - nt ** 2) * tile * tile * 4
+            (6 * nt ** 3 - nt ** 2) * tile * tile * 4
         assert r["planner_ms"] > 0 and r["xfer_ms"] > 0
         assert r["kernel_ms"] > 0 and r["host_ms"] > 0
         assert r["planner_ms"] + r["xfer_ms"] + r["kernel_ms"] + \
@@ -80,8 +82,8 @@ def test_phases_of_the_tiny_kmeans_cell(tiny_phases):
         waves = {sp[2] for sp in r["spans"] if sp[0] == "EXEC"}
         assert waves == set(range(len(waves)))
     s = res["summary"]
-    assert s["program_s.traced"] == traced["dur_s"]
-    assert s["program_s.untraced"] == untraced["dur_s"]
+    assert s["unit_s.traced"] == traced["dur_s"]
+    assert s["unit_s.untraced"] == untraced["dur_s"]
     assert s["slowest_unit"] is traced
 
 
@@ -92,7 +94,7 @@ def test_profiled_unit_nests_program_annotations_in_bench_ones(tiny_phases):
     assert {"cmm.PLAN", "cmm.ADDMUL", "cmm.GATHER", "cmm.H2D",
             "cmm.KERNEL", "cmm.D2H", "cmm.STORE",
             "cmm.ASSEMBLE"} <= set(res["reduction"]["annotations"])
-    anns = host_annotations(str(trace_dir))
+    _, anns = load_events(str(trace_dir), 1)
     (prog,) = [a for a in anns if a[0] == "bench.program"]
     (plan,) = [a for a in anns if a[0] == "bench.plan"]
     (ex,) = [a for a in anns if a[0] == "bench.execute"]
@@ -101,6 +103,28 @@ def test_profiled_unit_nests_program_annotations_in_bench_ones(tiny_phases):
     for name, s, e in cmm:
         outer = plan if name in ("cmm.PLAN", "cmm.FUSE", "cmm.CACHE") else ex
         assert outer[1] <= s and e <= outer[2], name
+
+
+def test_phases_of_the_tiny_markov_session_sum_its_persists(tmp_path):
+    res = run_phases("markov.session", SEED, 2, None, 1, overrides=TINY,
+                     log=lambda s: None, trace_dir=tmp_path)
+    n, tile = TINY["n"], TINY["tile"]
+    nt = n // tile
+    recs = res["records"]
+    assert [(r["traced"], r["profiled"]) for r in recs] == \
+        [(True, False), (True, False), (True, True)]
+    for r in recs:
+        # 3 steps of nt^2 mat-vec tasks: P, u and C tiles up, C down
+        assert r["xfer_mib"] * 2 ** 20 == \
+            3 * nt ** 2 * (tile ** 2 + 3 * tile) * 4
+        # the u persist and each of the 3 steps plan once
+        assert [sp[0] for sp in r["spans"]].count("PLAN") == 4
+        assert r["kernel_ms"] > 0 and r["xfer_ms"] > 0
+        assert r["planner_ms"] + r["xfer_ms"] + r["kernel_ms"] + \
+            r["host_ms"] <= 1e3 * r["dur_s"]
+    assert res["summary"]["unit_s.untraced"] is None
+    with pytest.raises(ValueError, match="traced units only"):
+        run_phases("markov.session", SEED, 1, 1, 0, overrides=TINY)
 
 
 def test_unit_phases_counts_host_groups_and_skips_device_ones():

@@ -6,10 +6,11 @@ Two steps, kept apart so that the second can be tested on made-up events:
   and returns plain tuples ``(name, start_ns, end_ns)``: the operations on
   each TPU device plane (line ``XLA Ops``, named by their HLO instruction
   without its operands), and the host annotations whose
-  names start with ``bench.`` (the benchmark's own ``TraceAnnotation``s).
+  names start with ``bench.`` (the benchmark's own ``TraceAnnotation``s)
+  or ``cmm.`` (the program's phase spans).
 * :func:`reduce_trace` turns those tuples into busy time, the idle share,
   the device operations that took most time and the idle time by what the
-  host was doing (the innermost ``bench.`` annotation around it).
+  host was doing (the innermost annotation around it).
 
 Busy time is the union of the operation intervals, so operations that
 overlap count once.  The traced window runs from the start of the first
@@ -29,7 +30,7 @@ Interval = Tuple[str, float, float]
 
 DEVICE_PLANE = re.compile(r"/device:TPU:(\d+)")
 DEVICE_OPS_LINE = "XLA Ops"
-ANNOTATION_PREFIX = "bench."
+ANNOTATION_PREFIXES = ("bench.", "cmm.")
 NO_ANNOTATION = "(none)"
 
 
@@ -42,7 +43,8 @@ def op_name(hlo: str) -> str:
 def load_events(trace_dir: str, n_devices: int
                 ) -> Tuple[Dict[str, List[Interval]], List[Interval]]:
     """Device operations of the first ``n_devices`` TPU planes and the
-    ``bench.`` host annotations of the newest trace under ``trace_dir``."""
+    ``bench.`` and ``cmm.`` host annotations of the newest trace under
+    ``trace_dir``."""
     from jax.profiler import ProfileData
     files = sorted(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
                              recursive=True), key=os.path.getmtime)
@@ -65,7 +67,7 @@ def load_events(trace_dir: str, n_devices: int
             for line in plane.lines:
                 anns.extend((e.name, e.start_ns, e.end_ns)
                             for e in line.events
-                            if e.name.startswith(ANNOTATION_PREFIX))
+                            if e.name.startswith(ANNOTATION_PREFIXES))
     return ops, anns
 
 
@@ -104,12 +106,19 @@ def _innermost_segments(anns: Sequence[Interval], lo: float, hi: float
                         ) -> List[Tuple[str, float, float]]:
     """Split ``[lo, hi]`` at every annotation boundary and label each piece
     with the innermost annotation around it (the latest to start, then the
-    earliest to end), or ``NO_ANNOTATION``."""
+    earliest to end), or ``NO_ANNOTATION``.  One sweep: an annotation
+    joins the open ones at its start and leaves once it ends before a
+    piece does, since the pieces only move later."""
     pts = sorted({lo, hi} | {t for _, s, e in anns for t in (s, e)
                              if lo < t < hi})
-    segs = []
+    by_start = sorted(anns, key=lambda x: x[1])
+    k, open_, segs = 0, [], []
     for a, b in zip(pts, pts[1:]):
-        around = [(s, -e, name) for name, s, e in anns if s <= a and e >= b]
+        while k < len(by_start) and by_start[k][1] <= a:
+            open_.append(by_start[k])
+            k += 1
+        open_ = [x for x in open_ if x[2] >= b]
+        around = [(s, -e, name) for name, s, e in open_]
         segs.append((max(around)[2] if around else NO_ANNOTATION, a, b))
     return segs
 
