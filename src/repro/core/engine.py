@@ -76,7 +76,8 @@ class Plan:
     #: those bytes priced through the TimeModel's spill bandwidths
     spill_seconds: float = 0.0
     #: flight recorder: the planner's spans (a PLAN span around the call,
-    #: FUSE/CACHE/TILE/HEFT/SIM/WAVES children; node -1, the master)
+    #: FUSE (with CHAIN inside)/CACHE/TILE/HEFT/SIM/WAVES children; node
+    #: -1, the master)
     spans: list = field(default_factory=list)
 
     @property
@@ -263,7 +264,8 @@ class CMMEngine:
         cache even though each step consumes fresh handles.
 
         The plan carries the planner's spans (``Plan.spans``); a cache
-        hit records only FUSE and CACHE under its PLAN span.
+        hit records only FUSE (with CHAIN inside) and CACHE under its PLAN
+        span.
         """
         tracer = Tracer(node=-1)
         with tracer.span("PLAN"):
@@ -291,7 +293,7 @@ class CMMEngine:
             with tracer.span("FUSE"):
                 roots, report = optimize_many(
                     roots, fold_transpose=tile[0] == tile[1],
-                    fuse_epilogue=self.fuse_epilogue)
+                    fuse_epilogue=self.fuse_epilogue, tracer=tracer)
 
         key = None
         if self.plan_cache:

@@ -14,6 +14,11 @@ The engine runs these rewrite passes over the lazy expression DAG *before*
 * **CSE** — structurally identical subexpressions (same op, canonicalised
   parents and value-relevant payload) are merged, so a shared subexpression
   is computed once;
+* **matrix-chain order** — each chain of MATMULs whose interior products
+  have one consumer is re-associated for the fewest flops (the classic
+  O(k³) dynamic programme over the operand shapes), so the paper's Markov
+  ``(P @ P @ P) @ u`` runs as three mat-vecs ``P @ (P @ (P @ u))``.  A
+  written order that is already among the cheapest is kept as it is;
 * **elementwise fusion** — maximal connected regions of
   EWISE/SCALE/ADD/SUB/EWMUL nodes whose interior nodes have a single
   consumer collapse into one FUSED node.  A FUSED node executes as *one*
@@ -49,6 +54,7 @@ from typing import Dict, List, Optional, Sequence, Set, Tuple
 
 import numpy as np
 
+from ..runtime.telemetry import NULL_TRACER, Tracer
 from .graph import epilogue_payload, matmul_epilogue, matmul_flags
 from .lazy import (ClusteredMatrix, EWISE_FNS, Op, apply_scale, topo_order,
                    topo_order_many)
@@ -72,6 +78,9 @@ class FusionReport:
     fused_ops: int = 0          # elementwise nodes swallowed by FUSED regions
     epilogues_fused: int = 0    # FUSED/elementwise nodes folded into a MATMUL
     epilogue_ops: int = 0       # arithmetic instrs now running as epilogues
+    chains_reordered: int = 0   # matmul chains re-associated for fewer flops
+    matmul_flops_written: int = 0   # 2·m·k·n over the MATMULs as written
+    matmul_flops_planned: int = 0   # ... and as planned
 
     def as_dict(self) -> Dict[str, int]:
         return dict(self.__dict__)
@@ -228,7 +237,148 @@ def cse_many(roots: Sequence[ClusteredMatrix],
 
 
 # ---------------------------------------------------------------------------
-# pass 3: elementwise-chain fusion
+# pass 3: matrix-chain order
+# ---------------------------------------------------------------------------
+
+def _chain_link(node: ClusteredMatrix) -> bool:
+    """A MATMUL with no epilogue (its value is the bare product) whose
+    operands have its dtype: a link of a matmul chain."""
+    return node.op is Op.MATMUL and len(node.parents) == 2 \
+        and matmul_epilogue(node.payload) is None \
+        and all(p.dtype == node.dtype for p in node.parents)
+
+
+def _matmul_flops(node: ClusteredMatrix) -> int:
+    """2·m·k·n of one MATMUL, k read through its transposed-operand flag."""
+    a = node.parents[0]
+    k = a.shape[0] if matmul_flags(node.payload)[0] else a.shape[1]
+    return 2 * node.shape[0] * k * node.shape[1]
+
+
+def order_matmul_chains_many(roots: Sequence[ClusteredMatrix],
+                             report: FusionReport,
+                             tracer: Tracer = NULL_TRACER
+                             ) -> List[ClusteredMatrix]:
+    """Re-associate every matmul chain for the fewest flops.
+
+    A chain is a maximal tree of plain MATMULs whose operands all have
+    the product's dtype, in which every interior product has exactly one
+    use, as an untransposed operand, and is no root.  A shared, transposed
+    or root product, and a product of mixed dtypes, bounds the chain as
+    one of its operands and is never duplicated.  So every chain has one
+    dtype, and no order makes a product round narrower than the written
+    one did.  Operands keep their transposed-operand flags.  The order is
+    the one of least ``2·m·k·n`` summed, and the written split of a range
+    wins a tie, so a written order among the cheapest (every chain of
+    square operands) comes back as the very same nodes.  Each chain of
+    three or more operands is ordered under a ``CHAIN`` span on
+    ``tracer``.
+    """
+    order = topo_order_many(roots)
+    root_uids = {r.uid for r in roots}
+    uses: Dict[int, int] = {}
+    linked: Set[int] = set()
+    for node in order:
+        if node.op is Op.MATMUL:
+            report.matmul_flops_written += _matmul_flops(node)
+        link = _chain_link(node)
+        flags = matmul_flags(node.payload)
+        for slot, p in enumerate(node.parents):
+            uses[p.uid] = uses.get(p.uid, 0) + 1
+            if link and not flags[slot]:
+                linked.add(p.uid)
+    interior = {n.uid for n in order
+                if n.uid in linked and uses[n.uid] == 1
+                and n.uid not in root_uids and _chain_link(n)}
+    if not interior:
+        return list(roots)
+
+    new: Dict[int, ClusteredMatrix] = {}
+    for node in order:
+        if node.uid in interior:
+            continue                    # rebuilt by its chain's top
+        if any(p.uid in interior for p in node.parents):
+            with tracer.span("CHAIN"):
+                new[node.uid] = _order_chain(node, interior, new, report)
+            continue
+        parents = tuple(new[p.uid] for p in node.parents)
+        new[node.uid] = node if parents == node.parents else \
+            ClusteredMatrix(node.op, node.shape, node.dtype, parents=parents,
+                            payload=node.payload, name=node.name)
+    return [new[r.uid] for r in roots]
+
+
+def _order_chain(top: ClusteredMatrix, interior: Set[int],
+                 new: Dict[int, ClusteredMatrix],
+                 report: FusionReport) -> ClusteredMatrix:
+    """Rebuild the chain under ``top`` in its cheapest association."""
+    leaves: List[Tuple[ClusteredMatrix, bool]] = []   # (operand, transposed)
+    # written products by the leaf range they cover -> (node, split)
+    written: Dict[Tuple[int, int], Tuple[ClusteredMatrix, int]] = {}
+
+    def operand(p: ClusteredMatrix, t: bool) -> None:
+        if p.uid in interior:           # used untransposed, so t is False
+            walk(p)
+        else:
+            leaves.append((new[p.uid], t))
+
+    def walk(node: ClusteredMatrix) -> None:
+        first = len(leaves)
+        (a, b), (ta, tb) = node.parents, matmul_flags(node.payload)
+        operand(a, ta)
+        split = len(leaves) - 1
+        operand(b, tb)
+        written[(first, len(leaves) - 1)] = (node, split)
+
+    walk(top)
+    k = len(leaves)
+    dims = [(a.shape[1] if t else a.shape[0]) for a, t in leaves]
+    a, t = leaves[-1]
+    dims.append(a.shape[0] if t else a.shape[1])
+
+    cost = [[0] * k for _ in range(k)]
+    best = [[0] * k for _ in range(k)]
+    for length in range(2, k + 1):
+        for i in range(k - length + 1):
+            j = i + length - 1
+            w = written.get((i, j))
+            splits = ([w[1]] if w is not None else []) + list(range(i, j))
+            # min() keeps the first of equals: the written split
+            cost[i][j], best[i][j] = min(
+                ((cost[i][s] + cost[s + 1][j]
+                  + 2 * dims[i] * dims[s + 1] * dims[j + 1], s)
+                 for s in splits), key=lambda cs: cs[0])
+
+    written_cost = sum(2 * dims[i] * dims[s + 1] * dims[j + 1]
+                       for (i, j), (_, s) in written.items())
+    if cost[0][k - 1] < written_cost:
+        report.chains_reordered += 1
+
+    def build(i: int, j: int) -> Tuple[ClusteredMatrix, bool]:
+        if i == j:
+            return leaves[i]
+        s = best[i][j]
+        a, ta = build(i, s)
+        b, tb = build(s + 1, j)
+        w = written.get((i, j))
+        if w is not None and w[1] == s:
+            node = w[0]
+            if node.parents == (a, b) and \
+                    matmul_flags(node.payload) == (ta, tb):
+                return node, False
+        name = w[0].name if w is not None else ""
+        shape = (a.shape[1] if ta else a.shape[0],
+                 b.shape[0] if tb else b.shape[1])
+        return ClusteredMatrix(Op.MATMUL, shape, top.dtype,
+                               parents=(a, b),
+                               payload=(ta, tb) if ta or tb else None,
+                               name=name), False
+
+    return build(0, k - 1)[0]
+
+
+# ---------------------------------------------------------------------------
+# pass 4: elementwise-chain fusion
 # ---------------------------------------------------------------------------
 
 def _consumers(roots: Sequence[ClusteredMatrix]) -> Dict[int, Set[int]]:
@@ -333,7 +483,7 @@ def fuse_elementwise_many(roots: Sequence[ClusteredMatrix],
 
 
 # ---------------------------------------------------------------------------
-# pass 4: matmul-epilogue fusion
+# pass 5: matmul-epilogue fusion
 # ---------------------------------------------------------------------------
 
 def _as_epilogue_prog(node: ClusteredMatrix,
@@ -462,16 +612,27 @@ def optimize(root: ClusteredMatrix, fold_transpose: bool = True,
 
 def optimize_many(roots: Sequence[ClusteredMatrix],
                   fold_transpose: bool = True, fuse: bool = True,
-                  fuse_epilogue: bool = True
+                  fuse_epilogue: bool = True, tracer: Tracer = NULL_TRACER
                   ) -> Tuple[List[ClusteredMatrix], FusionReport]:
     """Optimize several roots as ONE program: every pass (identity folds,
-    CSE, elementwise fusion, matmul-epilogue fusion) runs over the union
-    DAG, so subexpressions shared *across* roots are merged — the
-    ``compute_many`` shared-CSE contract."""
+    CSE, matrix-chain order, elementwise fusion, matmul-epilogue fusion)
+    runs over the union DAG, so subexpressions shared *across* roots are
+    merged — the ``compute_many`` shared-CSE contract.  Chain order runs
+    after CSE, so that a product used twice stays one, and CSE runs again
+    after a re-association, so that a product it exposes is shared; each
+    chain it orders is a ``CHAIN`` span on ``tracer``."""
     report = FusionReport(nodes_before=len(topo_order_many(roots)))
     roots = fold_identities_many(roots, report,
                                  fold_transpose=fold_transpose)
     roots = cse_many(roots, report)
+    roots = order_matmul_chains_many(roots, report, tracer)
+    if report.chains_reordered:
+        roots = cse_many(roots, report)
+        report.matmul_flops_planned = sum(
+            _matmul_flops(n) for n in topo_order_many(roots)
+            if n.op is Op.MATMUL)
+    else:
+        report.matmul_flops_planned = report.matmul_flops_written
     if fuse:
         roots = fuse_elementwise_many(roots, report)
         if fuse_epilogue:

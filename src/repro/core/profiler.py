@@ -1,7 +1,8 @@
 """Offline profiling (CMM §3.4).
 
 Runs each task family over a grid of operand sizes on the actual machine,
-times it, and fits the Table-1 interpolation equations by OLS.  The fitted
+times it, and fits the Table-1 interpolation equations by least squares
+(non-negative coefficients, ``PolyModel.fit``).  The fitted
 ``TimeModel`` is persisted to JSON and reused by the scheduler/simulator —
 profiling is *offline*, scheduling uses only the model (as in the paper).
 """
@@ -30,7 +31,8 @@ def profile_matmul(sizes: Sequence[int], reps: int = 3,
     rng = rng or np.random.default_rng(0)
     dims_list, times = [], []
     for m in sizes:
-        for k in sizes:
+        # k = 1: the mat-vec tiles of a chain ordered for the fewest flops
+        for k in (*sizes, 1):
             a = rng.standard_normal((m, m))
             b = rng.standard_normal((m, k))
             c = np.zeros((m, k))

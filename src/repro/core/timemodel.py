@@ -1,8 +1,8 @@
 """Time-prediction model (CMM §3.4, Table 1).
 
 Each task kind has an interpolation equation — a multivariate polynomial in
-the operand dimensions — whose coefficients are fitted by ordinary least
-squares on offline-profiled timings:
+the operand dimensions — whose coefficients are fitted by least squares,
+held non-negative, on offline-profiled timings:
 
     (n,1)  op (n,1)   +,-,x      a0 + a1*n
     (m,n)      sin,cos           a0 + a1*n + a2*m + a3*m*n
@@ -78,11 +78,21 @@ class PolyModel:
     @staticmethod
     def fit(family: str, dims_list: Sequence[Sequence[int]],
             times: Sequence[float]) -> "PolyModel":
+        from scipy.optimize import nnls     # only profiling fits a model
+
         X = np.stack([FEATURES[family](d) for d in dims_list])
         y = np.asarray(times, dtype=np.float64)
-        # OLS via lstsq (the paper's ordinary-least-squares regression)
-        coef, *_ = np.linalg.lstsq(X, y, rcond=None)
-        return PolyModel(family, coef)
+        # the paper's least-squares regression, with every coefficient
+        # held non-negative: each term is a cost, so the equation never
+        # goes below zero and grows with every dimension.  Unconstrained
+        # OLS on timings from a loaded host can price a tile outside the
+        # profiled sizes at <= 0 and a mat-vec at a full GEMM's time.
+        # Columns are scaled to unit norm for the solve (the constraint
+        # is invariant under positive scaling).
+        norm = np.linalg.norm(X, axis=0)
+        norm[norm == 0] = 1.0
+        coef, _ = nnls(X / norm, y)
+        return PolyModel(family, coef / norm)
 
     def r2(self, dims_list, times) -> float:
         y = np.asarray(times)

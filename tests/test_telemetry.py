@@ -545,6 +545,62 @@ def test_spans_reach_the_profiler_as_cmm_annotations(tmp_path):
                    if n == "cmm.ADDMUL")
 
 
+def _markov(n=KM_N, seed=0):
+    """The paper's Markov as written, ``(P @ P @ P) @ u``: one chain,
+    which the planner orders as three mat-vecs."""
+    P = CM.rand(n, n, seed=seed, dtype=np.float32)
+    u = CM.rand(n, 1, seed=seed + 1, dtype=np.float32)
+    return (P @ P @ P) @ u
+
+
+def test_chain_span_nests_in_plan_on_a_miss_and_a_hit():
+    eng = CMMEngine(timemodel=TM)
+    miss = eng.plan(_markov(seed=0), tile=KM_TILE)
+    hit = eng.plan(_markov(seed=5), tile=KM_TILE)
+    assert not miss.cache_hit and hit.cache_hit
+    for plan in (miss, hit):
+        (top,) = [s for s in plan.spans if s.cat == "PLAN"]
+        (fuse,) = [s for s in plan.spans if s.cat == "FUSE"]
+        (chain,) = [s for s in plan.spans if s.cat == "CHAIN"]
+        assert _inside(chain, fuse) and _inside(fuse, top)
+        assert chain.node == -1
+    # K-Means has no chain of three operands: no CHAIN span
+    km = eng.plan(_kmeans(_kmeans_arrays()), tile=KM_TILE)
+    assert "CHAIN" not in {s.cat for s in km.spans}
+
+
+@pytest.mark.parametrize("program", ["markov", "kmeans"])
+def test_fusion_report_counts_the_chain_pass(program):
+    n = KM_N
+    expr = _markov() if program == "markov" else \
+        _kmeans(_kmeans_arrays())
+    rep = CMMEngine(timemodel=TM).plan(expr, tile=KM_TILE).fusion.as_dict()
+    want = {"markov": (1, 2 * 2 * n ** 3 + 2 * n ** 2, 3 * 2 * n ** 2),
+            "kmeans": (0, 2 * 2 * n ** 3, 2 * 2 * n ** 3)}[program]
+    assert (rep["chains_reordered"], rep["matmul_flops_written"],
+            rep["matmul_flops_planned"]) == want
+
+
+def test_chain_span_reaches_the_profiler_under_plan(tmp_path):
+    import glob
+    import jax
+    from jax.profiler import ProfileData
+    eng = CMMEngine(timemodel=TM)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eng.plan(_markov(), tile=KM_TILE)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    anns = [(e.name, e.start_ns, e.end_ns)
+            for p in ProfileData.from_file(path).planes
+            if p.name.startswith("/host:") for line in p.lines
+            for e in line.events if e.name.startswith("cmm.")]
+    (plan,) = [a for a in anns if a[0] == "cmm.PLAN"]
+    (chain,) = [a for a in anns if a[0] == "cmm.CHAIN"]
+    assert plan[1] <= chain[1] and chain[2] <= plan[2]
+
+
 def test_disabled_and_jax_free_tracers_annotate_nothing(monkeypatch):
     import sys
     from repro.runtime import telemetry

@@ -4,7 +4,8 @@ import pytest
 
 from repro.core.graph import Task, TaskKind, TileRef
 from repro.core.machine import ClusterSpec, c5_9xlarge
-from repro.core.profiler import profile_comm_synthetic, profile_machine
+from repro.core.profiler import (profile_comm_synthetic, profile_machine,
+                                 profile_matmul)
 from repro.core.timemodel import (PolyModel, TimeModel, analytic_time_model,
                                   features_ewise, features_matmul)
 
@@ -27,6 +28,36 @@ def test_ols_recovers_synthetic_coefficients():
     pred = model.predict((512, 512, 512))
     want = features_matmul((512, 512, 512)) @ true
     assert abs(pred - want) / want < 0.1
+
+
+@pytest.mark.parametrize("seed", [0, 6, 9])
+def test_fit_on_loaded_timings_stays_positive_and_grows(seed):
+    """Timings from a loaded host, each inflated 1-30x at random, on the
+    profiler's grid with its mat-vec tiles.  Plain OLS prices a tile
+    outside the grid at <= 0 here (a 384^3 GEMM tile for seed 6, a 384^2
+    mat-vec for seeds 0 and 9).  The fit holds every coefficient
+    non-negative, so it prices both above zero, the mat-vec below the
+    GEMM, and the larger GEMM above the smaller one."""
+    rng = np.random.default_rng(seed)
+    true = np.array([2e-5, 0, 0, 0, 0, 0, 0, 5e-11])
+    sizes = (64, 128, 256)
+    dims = [(m, m, k) for m in sizes for k in (*sizes, 1)]
+    times = [features_matmul(d) @ true * rng.uniform(1, 30) for d in dims]
+    X = np.stack([features_matmul(d) for d in dims])
+    ols, *_ = np.linalg.lstsq(X, np.array(times), rcond=None)
+    mm, mv = (384, 384, 384), (384, 384, 1)
+    assert min(features_matmul(mm) @ ols, features_matmul(mv) @ ols) <= 0
+    model = PolyModel.fit("matmul", dims, times)
+    assert (model.coef >= 0).all()
+    assert 1e-9 < model.predict(mv) < model.predict(mm)
+    assert model.predict((256, 256, 256)) < model.predict(mm)
+
+
+def test_profile_matmul_times_mat_vec_tiles():
+    dims, times = profile_matmul((16, 32), reps=1)
+    assert dims == [(16, 16, 16), (16, 16, 32), (16, 16, 1),
+                    (32, 32, 16), (32, 32, 32), (32, 32, 1)]
+    assert len(times) == len(dims) and min(times) > 0
 
 
 def test_profile_machine_fits_reasonably():
