@@ -12,8 +12,9 @@ brings that to CMM:
   their shared-memory arenas survive across runs for the cluster/elastic
   backends) and a **residency table** mapping handles to live tiles;
 * :meth:`CMMSession.persist` computes an expression and leaves the result
-  **tiled in the executor's arenas** (local slab / per-node SharedMemory),
-  returning a :class:`ResidentMatrix`;
+  **tiled in the executor's arenas** (local slab / per-node SharedMemory;
+  HBM on the ``batched-pallas`` executor, wherever the device holds the
+  dtype exactly), returning a :class:`ResidentMatrix`;
 * a ``ResidentMatrix`` re-enters later expressions as a zero-cost,
   location-pinned leaf: tiling maps its tiles one-for-one onto RESIDENT
   tasks (no FILL, no gather), HEFT pins each RESIDENT task to the node
@@ -53,7 +54,8 @@ import numpy as np
 from .engine import CMMEngine, Plan
 from .lazy import ClusteredMatrix, Op, topo_order_many
 from .simulator import predict_reload_seconds
-from .tiling import normalize_tile, grid_of, tile_slices, result_sets_of
+from .tiling import (normalize_tile, grid_of, tile_slices, result_sets_of,
+                     to_host)
 
 _hid_counter = itertools.count(1)
 _hid_lock = threading.Lock()
@@ -102,8 +104,9 @@ class ResidentHandle:
 
     Pure data (no session/executor references) so it can cross a process
     boundary if it ever needs to; all tile *storage* lives in the session
-    (ndarrays for in-process backends, (node, segment, dtype) triples for
-    the multi-process ones).
+    (ndarrays for in-process backends, or ``jax.Array``s in HBM that the
+    ``batched-pallas`` executor put there; (node, segment, dtype) triples
+    for the multi-process ones).
     """
 
     hid: int
@@ -169,8 +172,9 @@ class SessionResidency:
         #: root expr uid -> handle (persisted OUTPUTS of this run)
         self.retain = retain
 
-    # -- executor read path (in-process backends) ---------------------------
-    def tile(self, leaf_uid: int, i: int, j: int) -> np.ndarray:
+    # -- executor read path (in-process backends: a host ndarray, or a
+    # device array on batched-pallas) ----------------------------------------
+    def tile(self, leaf_uid: int, i: int, j: int):
         h = self.handles[leaf_uid]
         return self._session._tiles[(h.hid, i, j)]
 
@@ -185,8 +189,7 @@ class SessionResidency:
         return {uid: h.hid for uid, h in self.handles.items()}
 
     # -- executor retention sinks -------------------------------------------
-    def retain_local(self, root_uid: int, i: int, j: int,
-                     arr: np.ndarray) -> None:
+    def retain_local(self, root_uid: int, i: int, j: int, arr) -> None:
         h = self.retain[root_uid]
         self._session._tiles[(h.hid, i, j)] = arr
         h.home[(i, j)] = 0
@@ -233,7 +236,8 @@ class CMMSession:
         self.engine = engine or CMMEngine()
         self.executor = executor
         self.tile = tile if tile is not None else self.engine.tile
-        self._tiles: Dict[Tuple[int, int, int], np.ndarray] = {}
+        #: in-process tiles: host ndarrays, or device arrays (HBM)
+        self._tiles: Dict[Tuple[int, int, int], object] = {}
         self._segs: Dict[Tuple[int, int, int], Tuple[int, str, str]] = {}
         self._handles: Dict[int, ResidentHandle] = {}
         self._closed = False
@@ -320,14 +324,21 @@ class CMMSession:
             key = (handle.hid, i, j)
             (r0, r1), (c0, c1) = rows[i], cols[j]
             if key in self._tiles:
-                out[r0:r1, c0:c1] = self._tiles[key]
+                out[r0:r1, c0:c1] = to_host(self._tiles[key])
             else:
                 self._attach_tile(key, out=out[r0:r1, c0:c1])
         return out
 
+    @property
+    def resident_device_bytes(self) -> int:
+        """Bytes of persisted tiles held in device memory (HBM)."""
+        return sum(t.nbytes for t in self._tiles.values()
+                   if not isinstance(t, np.ndarray))
+
     def free(self, handle: ResidentHandle) -> None:
         """Drop a handle's tiles from the arenas (its ResidentMatrix
-        leaves become unusable; dependents lose their recompute lineage)."""
+        leaves become unusable; dependents lose their recompute lineage).
+        Device tiles are released with the session's references."""
         if not handle.alive:
             return
         handle.alive = False
@@ -739,16 +750,16 @@ class CMMSession:
 
     def _read_tile(self, hid: int, i: int, j: int) -> np.ndarray:
         """One resident tile as a master-side host array (checkpoint
-        source).  In-process tiles are handed to the writer WITHOUT a
+        source).  In-process host tiles are handed to the writer WITHOUT a
         copy: a registered handle's tiles are immutable for its lifetime
         (``_persist_into`` replaces the dict entries, executors allocate
         fresh outputs, ``to_numpy`` assembles into a new array) and the
-        writer's reference keeps the array alive past ``free()``.
-        Cluster tiles are assembled from arena segments — already fresh
-        arrays."""
+        writer's reference keeps the array alive past ``free()``.  Device
+        tiles are pulled to the host; cluster tiles are assembled from
+        arena segments — both already fresh arrays."""
         key = (hid, i, j)
         if key in self._tiles:
-            return self._tiles[key]
+            return to_host(self._tiles[key])
         return self._attach_tile(key)
 
     def _pickle_lineage(self, h: ResidentHandle) -> Optional[bytes]:

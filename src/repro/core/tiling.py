@@ -341,6 +341,14 @@ def result_sets_of(g) -> List[ResultSet]:
                       {}, True)]
 
 
+def to_host(t):
+    """A tile as a host ndarray: ``t`` itself where it is one, else a copy
+    pulled from the device (a ``jax.Array`` that a device session keeps)."""
+    import numpy as np
+
+    return t if isinstance(t, np.ndarray) else np.asarray(t)
+
+
 def assemble(tile_values: Dict[TileRef, "object"],
              shape: Tuple[int, int], tile: Tuple[int, int],
              tensor_uid: int):

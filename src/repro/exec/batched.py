@@ -19,13 +19,24 @@ This backend removes it by *batching*:
 
 Flight recorder: one EXEC span per group call.  A device ADDMUL group's
 span holds one child span per phase — GATHER (stack and make contiguous
-on the host), H2D, KERNEL, D2H, STORE (into the arena) — each ended by
-the data it moved being ready; an ASSEMBLE span covers the result
-assembly after the last wave.  ``stats`` counts ``h2d_bytes``,
-``d2h_bytes``, ``host_copy_bytes`` (stacked gathers, contiguity and
-dtype copies, device-path stores) and ``staging_allocs`` /
-``staging_reuses`` (device-path staging takes; also each GATHER span's
-``args``).
+on the host, or on the device for device tiles), H2D, KERNEL, D2H,
+STORE (into the arena) — each ended by the data it moved being ready;
+an ASSEMBLE span covers the result assembly after the last wave.
+``stats`` counts ``h2d_bytes``, ``d2h_bytes``, ``host_copy_bytes``
+(stacked gathers, contiguity and dtype copies, device-path stores),
+``staging_allocs`` / ``staging_reuses`` (device-path staging takes; also
+each GATHER span's ``args``) and ``device_operand_tiles`` /
+``device_operand_bytes`` (device tiles a device group consumed where they
+lie; the tile count is in each GATHER span's ``args`` too).
+
+Device tiles (Pallas backend, in a session): a persisted root's tiles are
+kept in HBM as ``jax.Array``s, put up once at retention, wherever the
+device holds their dtype exactly (float32, bfloat16; not float64 under
+JAX's default x64-off, whose tiles stay host arrays).  A later run's
+RESIDENT tasks alias them, and a device ADDMUL group stacks an operand
+that holds any of them on the device, with no staging buffer and no
+upload of those tiles.  Every other reader pulls a device tile to the
+host on demand (``core.tiling.to_host``), counted in ``d2h_bytes``.
 
 Buffer arena: every group's output tiles live in ONE stacked slab
 ``(group, tm, tn)``; each tile buffer is a zero-copy view ``slab[i]``.
@@ -64,6 +75,7 @@ and picks the cheaper strategy per plan.
 """
 from __future__ import annotations
 
+import functools
 import threading
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
@@ -75,7 +87,7 @@ from ..core.graph import (Task, TaskGraph, TaskKind, TileRef,
 from ..core.lazy import EWISE_FNS, Op, apply_scale, leaf_slice
 from ..core.machine import ClusterSpec
 from ..core.timemodel import CostCache, TimeModel
-from ..core.tiling import assemble, result_sets_of, tile_slices
+from ..core.tiling import assemble, result_sets_of, tile_slices, to_host
 from ..runtime.telemetry import NULL_TRACER, Tracer
 
 
@@ -234,6 +246,30 @@ def stack_into(buf: np.ndarray, tiles: Sequence[np.ndarray],
     return buf
 
 
+def device_holds(dtype) -> bool:
+    """Whether the device keeps ``dtype`` exactly: float32 and bfloat16
+    do, float64 under JAX's default x64-off does not."""
+    import jax
+    return jax.dtypes.canonicalize_dtype(dtype) == np.dtype(dtype)
+
+
+@functools.lru_cache(maxsize=None)
+def _device_stack_fn():
+    """``stack(tiles, transpose, dtype)``: one stacked device operand made
+    from device tiles in one jitted copy, each tile's ``.T`` where
+    ``transpose``, cast to ``dtype``; jit keeps one program per
+    (count, shape, dtype)."""
+    import jax
+    import jax.numpy as jnp
+
+    def stack(tiles, transpose, dtype):
+        out = jnp.stack(tiles)
+        if transpose:
+            out = out.transpose(0, 2, 1)
+        return out.astype(dtype)
+    return jax.jit(stack, static_argnames=("transpose", "dtype"))
+
+
 class StagingPool:
     """Free host staging buffers of the device path, keyed on
     ``(shape, dtype)``; thread-safe.
@@ -333,16 +369,25 @@ class WaveExecutor:
         self.stats["host_copy_bytes"] += out.nbytes
         return out
 
+    def _host(self, t) -> np.ndarray:
+        """Tile ``t`` as a host array; a pull of a device tile counts in
+        ``d2h_bytes``."""
+        if isinstance(t, np.ndarray):
+            return t
+        t = to_host(t)
+        self.stats["d2h_bytes"] += t.nbytes
+        return t
+
     def _gather(self, refs, buffers, arena) -> np.ndarray:
         if len(refs) == 1:
             self.stats["zero_copy_gathers"] += 1
-            return buffers[refs[0]][None]
+            return self._host(buffers[refs[0]])[None]
         run = arena.contiguous_run(refs)
         if run is not None and run.shape[0] == len(refs):
             self.stats["zero_copy_gathers"] += 1
             return run
         self.stats["copied_gathers"] += 1
-        return self._stack([buffers[r] for r in refs])
+        return self._stack([self._host(buffers[r]) for r in refs])
 
     def _matmul_operands(self, tasks, buffers, arena):
         """Stacked A and B of a matmul group, transposed as the payload's
@@ -395,7 +440,8 @@ class WaveExecutor:
             return
 
         # elementwise families: one vectorized call over stacked operands
-        ins0 = self._gather([t.ins[0] for t in tasks], buffers, arena)
+        if kind is not TaskKind.FUSED:
+            ins0 = self._gather([t.ins[0] for t in tasks], buffers, arena)
         if kind in (TaskKind.ADD, TaskKind.SUB, TaskKind.EWMUL):
             ins1 = self._gather([t.ins[1] for t in tasks], buffers, arena)
             ufunc = {TaskKind.ADD: np.add, TaskKind.SUB: np.subtract,
@@ -497,12 +543,17 @@ class WaveExecutor:
             self._apply_epilogue(epi, tasks, c3, buffers, arena)
 
     def _device_operand(self, refs, buffers, arena, taken, transpose=False,
-                        dtype=None) -> np.ndarray:
+                        dtype=None):
         """One stacked operand of a device group, C-contiguous and of
-        ``dtype`` (default: the tiles'): a zero-copy view where the tiles
-        already lie so, else one copy into a staging buffer, which is
-        appended to ``taken`` (each tile's ``.T`` where ``transpose``)."""
+        ``dtype`` (default: the tiles'), each tile's ``.T`` where
+        ``transpose``.  Where any tile is a device array, a device array
+        stacked where the tiles lie (:meth:`_device_stack`); otherwise a
+        host array: a zero-copy view where the tiles already lie so, else
+        one copy into a staging buffer, which is appended to ``taken``."""
         tiles = [buffers[r] for r in refs]
+        on_dev = [not isinstance(t, np.ndarray) for t in tiles]
+        if any(on_dev):
+            return self._device_stack(tiles, on_dev, transpose, dtype)
         dtype = tiles[0].dtype if dtype is None else np.dtype(dtype)
         if not transpose:
             run = tiles[0][None] if len(refs) == 1 else \
@@ -519,6 +570,25 @@ class WaveExecutor:
         self.stats["staging_reuses" if reused else "staging_allocs"] += 1
         self.stats["host_copy_bytes"] += buf.nbytes
         return stack_into(buf, tiles, transpose)
+
+    def _device_stack(self, tiles, on_dev, transpose, dtype):
+        """A device operand stacked on the device in one jitted copy; the
+        host tiles among ``tiles`` go up first (counted in ``h2d_bytes``),
+        the device ones are read where they lie (counted in
+        ``device_operand_tiles`` / ``device_operand_bytes``)."""
+        import jax
+        st = self.stats
+        up = [t for t, d in zip(tiles, on_dev) if not d]
+        if up:
+            put = iter(jax.device_put(up))
+            tiles = [t if d else next(put) for t, d in zip(tiles, on_dev)]
+            st["h2d_bytes"] += sum(t.nbytes for t, d in zip(tiles, on_dev)
+                                   if not d)
+        st["device_operand_tiles"] += sum(on_dev)
+        st["device_operand_bytes"] += sum(t.nbytes for t, d in
+                                          zip(tiles, on_dev) if d)
+        dtype = tiles[0].dtype if dtype is None else np.dtype(dtype)
+        return _device_stack_fn()(tiles, transpose=transpose, dtype=dtype)
 
     def _run_device_addmul(self, tasks, buffers, arena) -> None:
         """ADDMUL group through the Pallas GEMM: C += A @ B, with a fused
@@ -539,15 +609,24 @@ class WaveExecutor:
 
         with tr.span("GATHER") as sp:
             allocs, reuses = st["staging_allocs"], st["staging_reuses"]
-            host = [self._device_operand(outs, buffers, arena, taken),
-                    operand(0, ta, ab_dt), operand(1, tb, ab_dt)]
+            dev_tiles = st["device_operand_tiles"]
+            ops = [self._device_operand(outs, buffers, arena, taken),
+                   operand(0, ta, ab_dt), operand(1, tb, ab_dt)]
             if epi is not None:
-                host += [operand(j) for j in range(2, len(tasks[0].ins))]
+                ops += [operand(j) for j in range(2, len(tasks[0].ins))]
+            is_host = [isinstance(x, np.ndarray) for x in ops]
+            jax.block_until_ready([x for x, h in zip(ops, is_host)
+                                   if not h])
             sp.args["staging_allocs"] = st["staging_allocs"] - allocs
             sp.args["staging_reuses"] = st["staging_reuses"] - reuses
+            sp.args["device_operand_tiles"] = \
+                st["device_operand_tiles"] - dev_tiles
         with tr.span("H2D") as sp:
-            dev = jax.block_until_ready(jax.device_put(host))
-            sp.args["nbytes"] = nbytes = sum(x.nbytes for x in dev)
+            put = jax.block_until_ready(jax.device_put(
+                [x for x, h in zip(ops, is_host) if h]))
+            sp.args["nbytes"] = nbytes = sum(x.nbytes for x in put)
+            put = iter(put)
+            dev = [next(put) if h else x for x, h in zip(ops, is_host)]
         st["h2d_bytes"] += nbytes
         store_dt = None if epi is None else self._epilogue_store_dtype()
         with tr.span("KERNEL"):
@@ -572,7 +651,7 @@ class WaveExecutor:
                     # (jax may compute in f32; the plain path casts back
                     # to the accumulator dtype the same way)
                     res = self._counted(res, res.astype(np.result_type(
-                        *[x.dtype for x in [host[0]] + host[3:]]),
+                        *[x.dtype for x in [ops[0]] + ops[3:]]),
                         copy=False))
                 arena.register(outs, res)
                 for i, t in enumerate(tasks):
@@ -580,8 +659,35 @@ class WaveExecutor:
         # nothing refers to the device arrays made from the staging
         # buffers any more, so the next group may overwrite them (after an
         # error they are dropped instead: a transfer may still hold them)
-        del dev
+        del dev, put
         self.staging.give(taken)
+
+    def _retained(self, bufs) -> list:
+        """The tiles a session keeps of one persisted root.
+
+        The Pallas backend keeps in HBM every host tile whose dtype the
+        device holds exactly, put up in one ``device_put`` and counted in
+        ``h2d_bytes``; a device tile stays as it is.  Every other tile
+        stays a host array.  Wave tiles are views into per-wave SLABS —
+        retaining the view would pin the whole slab (every same-wave tile)
+        for the handle's lifetime, and INPUT-leaf views alias the user's
+        array — so a view-backed host tile is copied out; only standalone
+        arrays transfer zero-copy.
+        """
+        if self.backend == "pallas":
+            up = [k for k, b in enumerate(bufs)
+                  if isinstance(b, np.ndarray) and device_holds(b.dtype)]
+            if up:
+                import jax
+                put = jax.block_until_ready(
+                    jax.device_put([bufs[k] for k in up]))
+                self.stats["h2d_bytes"] += sum(x.nbytes for x in put)
+                bufs = list(bufs)
+                for k, x in zip(up, put):
+                    bufs[k] = x
+        return [np.ascontiguousarray(b)
+                if isinstance(b, np.ndarray) and b.base is not None else b
+                for b in bufs]
 
     # -- driver ------------------------------------------------------------
     def execute(self, plan) -> np.ndarray:
@@ -598,7 +704,8 @@ class WaveExecutor:
         self.stats = {"zero_copy_gathers": 0, "copied_gathers": 0,
                       "batched_calls": 0, "h2d_bytes": 0, "d2h_bytes": 0,
                       "host_copy_bytes": 0, "staging_allocs": 0,
-                      "staging_reuses": 0}
+                      "staging_reuses": 0, "device_operand_tiles": 0,
+                      "device_operand_bytes": 0}
         self._staged_keys = set()
 
         # readers per tile (+1 keeps result tiles alive for assembly and
@@ -644,28 +751,22 @@ class WaveExecutor:
                             arena.release_tile(r)
                             buffers.pop(r, None)
 
-        # retention: persisted roots' tiles move to the session store.
-        # Wave tiles are views into per-wave SLABS — retaining the view
-        # would pin the whole slab (every same-wave tile) for the
-        # handle's lifetime, and INPUT-leaf views alias the user's array
-        # — so view-backed tiles are copied out; only standalone arrays
-        # transfer zero-copy.
+        # retention: persisted roots' tiles move to the session store
+        # (``_retained``)
         retained = 0
         outs = []
         gather_bytes = 0
         with tracer.span("ASSEMBLE"):
             for rs in rsets:
                 if rs.gather:
-                    vals = {r: buffers[r] for r in rs.tiles}
+                    vals = {r: self._host(buffers[r]) for r in rs.tiles}
                     gather_bytes += sum(r.bytes for r in rs.tiles)
                     outs.append(assemble(vals, rs.shape, tile, rs.uid))
                 else:
-                    for r in rs.tiles:
-                        buf = buffers[r]
-                        if buf.base is not None:
-                            buf = np.ascontiguousarray(buf)
+                    kept = self._retained([buffers[r] for r in rs.tiles])
+                    for r, buf in zip(rs.tiles, kept):
                         residency.retain_local(rs.uid, r.i, r.j, buf)
-                        retained += 1
+                    retained += len(kept)
 
         if self._staged_keys:
             self.staging.retain(self._staged_keys)
