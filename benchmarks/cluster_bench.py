@@ -108,11 +108,11 @@ def run_case(n: int, tile: int, node_workers, reps: int = 1) -> dict:
 def _elastic_wall(plan, tm, chaos=(), reps: int = 2,
                   blas_threads=None):
     """Best-of-``reps`` elastic wall clock + the last run's stats/output."""
-    from repro.exec.elastic import ElasticClusterExecutor
+    from repro.exec.elastic import ClusterExecutor
     best, out, stats = float("inf"), None, None
     for _ in range(reps):
-        ex = ElasticClusterExecutor(timemodel=tm, chaos=chaos,
-                                    blas_threads=blas_threads)
+        ex = ClusterExecutor(timemodel=tm, chaos=chaos,
+                             blas_threads=blas_threads)
         t0 = time.perf_counter()
         out = ex.execute(plan)
         best = min(best, time.perf_counter() - t0)
@@ -221,7 +221,7 @@ def main() -> int:
                     help="small inputs, oracle-checked (the CI gates)")
     ap.add_argument("--elastic", action="store_true",
                     help="chaos leg: mid-run node kill + mid-run join "
-                         "through the elastic executor")
+                         "through the cluster executor under chaos")
     ap.add_argument("--out", default=None,
                     help="output JSON path (default: BENCH_cluster.json / "
                          "BENCH_elastic.json, with a _smoke suffix under "

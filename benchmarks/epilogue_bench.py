@@ -17,7 +17,7 @@ buys and guarantees on the paper chain ``relu((A @ B) + C)``:
   (``WaveExecutor(precision="mixed")``: f32 accumulate, bf16 store).
   GATED: output dtype is bfloat16 and values match the f64 eager oracle
   within the documented bf16 tolerance (rtol=atol=2e-2).
-* **roofline leg** — a chaos-throttled node on the elastic executor
+* **roofline leg** — a chaos-throttled node on the cluster executor
   must show up in the analytic roofline report
   (``core/roofline.py``): the throttled node is the ONLY below-band
   outlier (planned heterogeneity cancels in per-node peaks), and the
@@ -44,8 +44,7 @@ from repro.core import ClusteredMatrix as CM, CMMEngine, analytic_time_model
 from repro.core.machine import c5_9xlarge, hetero_spec
 from repro.core.roofline import roofline_report
 from repro.exec.batched import WaveExecutor
-from repro.exec.cluster import ClusterExecutor
-from repro.exec.elastic import ChaosEvent, ElasticClusterExecutor
+from repro.exec.elastic import ChaosEvent, ClusterExecutor
 from repro.exec.local import LocalExecutor
 from repro.runtime.membership import MembershipConfig
 
@@ -156,7 +155,7 @@ def run_roofline_chaos(n: int, tile: int, throttle_node: int = 3,
                        **FAST_NET)
     plan = _plan(_chain(n), tile, fuse_epilogue=True, spec=spec)
     ref = LocalExecutor().execute(plan)
-    exe = ElasticClusterExecutor(
+    exe = ClusterExecutor(
         timemodel=TM,
         membership=MembershipConfig(heartbeat_interval_s=0.05),
         chaos=[ChaosEvent(after_done=0, throttle_node=throttle_node,

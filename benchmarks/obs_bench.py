@@ -12,12 +12,12 @@ Measures what the flight recorder (``runtime/telemetry.py`` +
   valid Chrome-trace JSON.  Smoke runs record the ratio
   informationally — sub-second runs on shared CI hosts cannot resolve
   a 5% wall-clock delta.
-* **drift leg** — a chaos-throttled node on the elastic executor must
+* **drift leg** — a chaos-throttled node on the cluster executor must
   show up in the drift report: per-node residual rows for EVERY node
   of the spec, the throttled node flagged as a straggler prior, and
   the run still bit-identical to the local oracle.  The recovered
   priors are then fed back through
-  ``ElasticClusterExecutor(straggler_priors=...)`` (round-trip
+  ``ClusterExecutor(straggler_priors=...)`` (round-trip
   recorded informationally).
 
 Exit status is non-zero on any failed gate — wired into CI as the
@@ -40,8 +40,7 @@ import numpy as np
 from repro.core import ClusteredMatrix as CM, CMMEngine, analytic_time_model
 from repro.core.drift import drift_report
 from repro.core.machine import hetero_spec
-from repro.exec.cluster import ClusterExecutor
-from repro.exec.elastic import ChaosEvent, ElasticClusterExecutor
+from repro.exec.elastic import ChaosEvent, ClusterExecutor
 from repro.exec.local import LocalExecutor
 from repro.runtime.membership import MembershipConfig
 from repro.runtime.telemetry import chrome_trace
@@ -128,12 +127,12 @@ def run_drift_chaos(n: int, tile: int, throttle_node: int = 3,
     """Throttled-node chaos run: the drift report must flag exactly the
     slowed node as a straggler prior, with residual rows for every node
     of the spec, and the run must stay bit-identical to the local
-    oracle.  The priors then seed a fresh elastic run's membership
+    oracle.  The priors then seed a fresh cluster run's membership
     detector (round-trip recorded informationally)."""
     spec = _spec((2, 2, 1, 1))
     plan = _plan(_expr(n), tile, spec)
     ref = LocalExecutor().execute(plan)
-    exe = ElasticClusterExecutor(
+    exe = ClusterExecutor(
         timemodel=TM,
         membership=MembershipConfig(heartbeat_interval_s=0.05),
         chaos=[ChaosEvent(after_done=0, throttle_node=throttle_node,
@@ -144,7 +143,7 @@ def run_drift_chaos(n: int, tile: int, throttle_node: int = 3,
     flagged = rep.straggler_priors
 
     # round-trip: feed the recovered priors into a fresh run's detector
-    rt = ElasticClusterExecutor(
+    rt = ClusterExecutor(
         timemodel=TM,
         membership=MembershipConfig(heartbeat_interval_s=0.05),
         straggler_priors=flagged,

@@ -6,8 +6,8 @@ Measures what the tiered spill store (``runtime/spill.py`` + the bounded
 * **bit-identity leg** — the conformance program run with per-node
   ``mem_bytes`` at a third of its working set (footprint >= 3x budget)
   must spill for real (``spill_writes > 0``) and still produce the
-  **exact bytes** of the unbounded oracle at the same tile size, on both
-  the static cluster executor and the elastic executor.  GATED.
+  **exact bytes** of the unbounded oracle at the same tile size on the
+  cluster executor.  GATED.
 * **overhead leg** — the same program with a budget generous enough to
   never spill, so what is measured is pure bounded-arena bookkeeping
   (locked gets, LRU touches, byte accounting) against the unbounded
@@ -16,7 +16,7 @@ Measures what the tiered spill store (``runtime/spill.py`` + the bounded
   cannot amortise fixed per-run costs).  Skipped, per the repo's
   wall-clock policy, while the 1-minute load average exceeds 1.25/cpu.
 * **chaos leg** — ``mem_squeeze`` (shrink a node's budget mid-run) and
-  ``alloc_fail`` (fail the Nth allocation) fired against the elastic
+  ``alloc_fail`` (fail the Nth allocation) fired against the cluster
   executor: the run must complete bit-identically — the failures are
   absorbed by eviction and bounded retry, never a crash.  GATED.
 
@@ -39,8 +39,7 @@ import numpy as np
 
 from repro.core import ClusteredMatrix as CM, CMMEngine, analytic_time_model
 from repro.core.machine import hetero_spec
-from repro.exec.cluster import ClusterExecutor
-from repro.exec.elastic import ChaosEvent, ElasticClusterExecutor
+from repro.exec.elastic import ChaosEvent, ClusterExecutor
 
 REPS = 3          # best-of-N wall clocks (load spikes inflate, never deflate)
 LOAD_BAR = 1.25   # loadavg/cpu above which wall gates are skipped
@@ -76,27 +75,20 @@ def _ws(n):
 
 
 def run_bit_identity(n: int, tile: int) -> dict:
-    """Bounded (budget = ws/3) vs unbounded, bitwise, both executors."""
+    """Bounded (budget = ws/3) vs unbounded, bitwise."""
     budget = float(_ws(n) // 3)
-    ref = ClusterExecutor().execute(_plan(n, tile))
-    exc = ClusterExecutor()
-    got_c = exc.execute(_plan(n, tile, budget))
-    exe = ElasticClusterExecutor(timemodel=TM)
-    got_e = exe.execute(_plan(n, tile, budget))
+    ref = ClusterExecutor(timemodel=TM).execute(_plan(n, tile))
+    ex = ClusterExecutor(timemodel=TM)
+    got = ex.execute(_plan(n, tile, budget))
     return {
         "case": "spill_bit_identity", "n": n, "tile": tile,
         "budget_bytes": budget, "working_set_bytes": _ws(n),
-        "cluster_spill_writes": exc.stats["spill_writes"],
-        "cluster_faults": exc.stats["faults"],
-        "elastic_spill_writes": exe.stats["spill_writes"],
-        "elastic_faults": exe.stats["faults"],
-        "ok_spilled_for_real": bool(exc.stats["spill_writes"] > 0
-                                    and exe.stats["spill_writes"] > 0),
-        "ok_bitident_cluster": bool(np.array_equal(ref, got_c)),
-        "ok_bitident_elastic": bool(np.array_equal(ref, got_e)),
+        "cluster_spill_writes": ex.stats["spill_writes"],
+        "cluster_faults": ex.stats["faults"],
+        "ok_spilled_for_real": bool(ex.stats["spill_writes"] > 0),
+        "ok_bitident_cluster": bool(np.array_equal(ref, got)),
         "ok_no_leaked_spill_files": bool(
-            exc.stats["leaked_spill_files"] == 0
-            and exe.stats["leaked_spill_files"] == 0),
+            ex.stats["leaked_spill_files"] == 0),
     }
 
 
@@ -149,11 +141,11 @@ def run_overhead(n: int, tile: int, gate: bool = True) -> dict:
 
 
 def run_chaos(n: int, tile: int) -> dict:
-    """mem_squeeze + alloc_fail against the elastic executor under a
+    """mem_squeeze + alloc_fail against the cluster executor under a
     budget: graceful degradation (evict/retry), bit-identical result."""
     budget = float(_ws(n) // 2)
-    ref = ElasticClusterExecutor(timemodel=TM).execute(_plan(n, tile))
-    ex = ElasticClusterExecutor(
+    ref = ClusterExecutor(timemodel=TM).execute(_plan(n, tile))
+    ex = ClusterExecutor(
         timemodel=TM,
         chaos=(ChaosEvent(after_done=3, alloc_fail=0, alloc_fail_nth=2),
                ChaosEvent(after_done=5, mem_squeeze=1,
@@ -204,10 +196,8 @@ def main() -> int:
         if c["case"] == "spill_bit_identity":
             print(f"[spill] bit-identity n={c['n']} "
                   f"budget={c['budget_bytes']:.0f}B "
-                  f"(cluster {c['cluster_spill_writes']} writes/"
-                  f"{c['cluster_faults']} faults, elastic "
-                  f"{c['elastic_spill_writes']}/{c['elastic_faults']}) "
-                  f"{line}")
+                  f"({c['cluster_spill_writes']} writes/"
+                  f"{c['cluster_faults']} faults) {line}")
         elif c["case"] == "bounded_arena_overhead":
             print(f"[spill] overhead n={c['n']} wall "
                   f"{c['wall_unbounded_s']:.3f}s->"

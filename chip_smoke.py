@@ -11,9 +11,9 @@ One chip, in order:
    ``tpu_custom_call`` (compiled, not interpreted);
 3. the paper's Markov, K-Means and Synth programs (``benchmarks/cmm_suite.py``)
    at n = 8192, float32, tile 1024 through ``CMMEngine.run`` on the
-   ``batched-pallas`` executor, Markov also on ``kernel``; each result is
-   compared with ``expr.eager()`` (NumPy from the same seeds) by its
-   norm-wise relative error;
+   ``batched-pallas`` executor; each result is compared with
+   ``expr.eager()`` (NumPy from the same seeds) by its norm-wise relative
+   error;
 4. K-Means again at ``precision="mixed"`` (bf16 store, the 2e-2 tier);
 5. a ``CMMSession`` power iteration: persist P, then ``u = persist(P @ u)``
    five times, one gather at the end, compared with NumPy.
@@ -134,7 +134,6 @@ def paper_legs(ph: Phases, engine: CMMEngine, n: int, tile: int,
     expr = markov(n, dtype=dtype)
     ref = ph.run("markov/reference", expr.eager)
     leg("markov/batched-pallas", expr, ref, F32_BOUND, "batched-pallas")
-    leg("markov/kernel", expr, ref, F32_BOUND, "kernel")
 
     expr = kmeans(n, dtype=dtype)
     ref = ph.run("kmeans/reference", expr.eager)

@@ -100,7 +100,7 @@ class DriftReport:
     terms: List[TermDrift]
     #: nodes whose relative residual exceeded the band on the slow side
     #: — feed to ``MembershipService.seed_straggler_priors`` /
-    #: ``ElasticClusterExecutor(straggler_priors=...)``
+    #: ``ClusterExecutor(straggler_priors=...)``
     straggler_priors: List[int]
     band: float
     #: fleet-median actual/predicted ratio (the model's uniform bias)

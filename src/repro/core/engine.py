@@ -189,9 +189,8 @@ class CMMEngine:
         #: the unfused baseline (standalone FUSED tasks per tile)
         self.fuse_epilogue = fuse_epilogue
         self.plan_cache = plan_cache
-        #: elastic runtime mode: multi-node execution goes through the
-        #: fault-tolerant ``"elastic"`` backend and ``auto`` selection
-        #: prices churn risk (``tm.node_mtbf``) into the cluster strategy
+        #: elastic runtime mode: ``auto`` selection prices churn risk
+        #: (``tm.node_mtbf``) into the multi-process ``"cluster"`` strategy
         self.elastic = elastic
         #: memoized-cost + gap-timeline HEFT (identical schedules; see
         #: ``heft.heft_schedule(fast=...)``).  ``False`` restores the
@@ -462,19 +461,16 @@ class CMMEngine:
         """Execute through a backend from the ``repro.exec.EXECUTORS``
         registry:
 
-        * ``"local"``          — per-task threaded executor;
-        * ``"kernel"``         — per-task with Pallas addmul tiles;
+        * ``"local"``          — per-task threaded NumPy executor;
         * ``"batched"``        — wave-batched stacked-kernel executor;
         * ``"batched-pallas"`` — wave-batched, ADDMUL groups through
-          ``jax.vmap`` over the Pallas blocked GEMM;
+          ``jax.vmap`` over the Pallas blocked GEMM (the device path);
         * ``"cluster"``        — one worker process per cluster node,
-          HEFT node placements executed for real;
-        * ``"elastic"``        — the cluster backend under the elastic
+          HEFT node placements executed for real under the elastic
           control plane (membership, lineage recovery, re-planning);
         * ``"auto"``           — simulation-driven choice between the
           per-task, wave-batched and cluster strategies for this plan
-          (churn-priced, and routed through ``"elastic"``, when the
-          engine runs with ``elastic=True``).
+          (churn-priced when the engine runs with ``elastic=True``).
 
         ``run`` is the thin ONE-SHOT wrapper over the session execution
         path (``execute_plan``): plan, execute with an ephemeral executor,
@@ -498,11 +494,11 @@ class CMMEngine:
         registry."""
         if executor == "auto":
             executor = self.choose_executor(plan)
-        if executor in ("elastic", "cluster") and executor_obj is None \
+        if executor == "cluster" and executor_obj is None \
                 and "timemodel" not in exec_kw:
             # frontier re-planning inside the executor must price nodes
-            # with the same model the original schedule used; the cluster
-            # backends also price per-edge wire codecs against it
+            # with the same model the original schedule used; it also
+            # prices per-edge wire codecs against it
             exec_kw["timemodel"] = self.timemodel
         if executor_obj is None:
             from ..exec import make_executor
@@ -570,9 +566,9 @@ class CMMEngine:
 
         Under ``elastic=True`` the multi-process strategy is priced at
         its churn-adjusted makespan (expected lineage-recovery cost under
-        ``tm.node_mtbf``) and executed by the fault-tolerant backend —
-        an unreliable cluster can tip ``auto`` back to an in-process
-        strategy even when the pristine cluster prediction wins.
+        ``tm.node_mtbf``) — an unreliable cluster can tip ``auto`` back
+        to an in-process strategy even when the pristine cluster
+        prediction wins.
         """
         if not self.elastic:
             return plan.best_executor
@@ -581,7 +577,7 @@ class CMMEngine:
             best, t = "batched", plan.batched_makespan
         em = plan.elastic_makespan
         if em is not None and em < t:
-            best, t = "elastic", em
+            best, t = "cluster", em
         return best
 
     def theoretical_speedup(self, root: ClusteredMatrix, tile=None,

@@ -9,8 +9,8 @@ DistStat.jl's distributed arrays stay resident across calls; this module
 brings that to CMM:
 
 * :class:`CMMSession` owns a **long-lived executor** (worker processes and
-  their shared-memory arenas survive across runs for the cluster/elastic
-  backends) and a **residency table** mapping handles to live tiles;
+  their shared-memory arenas survive across runs for the cluster
+  backend) and a **residency table** mapping handles to live tiles;
 * :meth:`CMMSession.persist` computes an expression and leaves the result
   **tiled in the executor's arenas** (local slab / per-node SharedMemory;
   HBM on the ``batched-pallas`` executor, wherever the device holds the
@@ -21,7 +21,7 @@ brings that to CMM:
   whose arena holds the tile, and the simulator prices it at ~0 so
   ``auto`` verdicts stay honest;
 * :meth:`ResidentMatrix.to_numpy` gathers on demand;
-* on the **elastic** backend a resident tile lost to a node death is a
+* on the **cluster** backend a resident tile lost to a node death is a
   *recomputable root*: every handle carries the expression (lineage) that
   produced it, and the session transparently re-derives lost handles from
   lineage — numpywren-style recovery extended across runs.
@@ -77,7 +77,7 @@ def _ensure_hid_floor(n: int) -> None:
 
 
 class ResidentTilesLost(RuntimeError):
-    """Raised by an elastic executor when tiles of a resident handle were
+    """Raised by the cluster executor when tiles of a resident handle were
     on a node that died (and no live copy remains).  The session catches
     it, re-derives the named handles from lineage and retries the run."""
 
@@ -203,7 +203,7 @@ class SessionResidency:
 
 #: executor registry names that run inside the master process (tile storage
 #: is plain ndarrays owned by the session)
-_INPROC = ("local", "kernel", "batched", "batched-pallas")
+_INPROC = ("local", "batched", "batched-pallas")
 
 
 class CMMSession:
@@ -219,7 +219,7 @@ class CMMSession:
                 u = s.persist(P @ u)                  # no gather, no refill
             result = u.to_numpy()                     # one gather, at the end
 
-    ``executor`` is a registry name; for ``"cluster"``/``"elastic"`` the
+    ``executor`` is a registry name; for ``"cluster"`` the
     worker processes are spawned once and survive across runs, and
     persisted tiles live in the workers' shared-memory arenas.  ``close()``
     frees every live handle, audits the worker arenas for leaks (refcount
@@ -274,12 +274,9 @@ class CMMSession:
             from ..exec import make_executor
             self._exec = make_executor(executor, **exec_kw)
         elif executor == "cluster":
-            from ..exec.cluster import ClusterExecutor
-            self._exec = ClusterExecutor(session=True, **exec_kw)
-        elif executor == "elastic":
-            from ..exec.elastic import ElasticClusterExecutor
+            from ..exec.elastic import ClusterExecutor
             exec_kw.setdefault("timemodel", self.engine.timemodel)
-            self._exec = ElasticClusterExecutor(session=True, **exec_kw)
+            self._exec = ClusterExecutor(session=True, **exec_kw)
         else:
             raise ValueError(f"unknown session executor {executor!r}")
         if self._store is not None \
@@ -433,7 +430,7 @@ class CMMSession:
 
     # -- internals -----------------------------------------------------------
     def _sync_spec(self) -> None:
-        """After an elastic run, membership may have changed (deaths drain
+        """After a cluster run, membership may have changed (deaths drain
         nodes, joins append them).  Future plans must target the executor's
         current spec, or they would place tasks on nodes that left — and
         EVERY handle with tiles homed on a departed node is lost, not just

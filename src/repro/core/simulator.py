@@ -29,7 +29,23 @@ from .graph import Task, TaskGraph, TaskKind
 from .heft import Schedule, edge_bytes
 from .machine import ClusterSpec
 from .timemodel import CostCache, TimeModel
-from ..runtime.wire import BCAST_MIN_FANOUT, broadcast_tree
+from ..runtime.wire import BCAST_MIN_FANOUT
+
+
+def _relay_tree(src: int, dsts) -> Dict[int, List[int]]:
+    """Binary relay tree for one fan-out edge: each relay node mapped to
+    its children over ``[src] + sorted(dsts)`` (position ``i`` feeds
+    ``2i+1`` and ``2i+2``).  How the simulator prices an edge the cluster
+    executor serves through relays (each holder serves at most
+    ``BCAST_MIN_FANOUT - 1`` readers at a time; landed copies serve the
+    rest)."""
+    order = [src] + sorted(set(int(d) for d in dsts) - {src})
+    tree: Dict[int, List[int]] = {}
+    for i, parent in enumerate(order):
+        kids = order[2 * i + 1: 2 * i + 3]
+        if kids:
+            tree[parent] = kids
+    return tree
 
 
 @dataclass
@@ -230,7 +246,7 @@ def simulate(g: TaskGraph, sched: Schedule, spec: ClusterSpec, tm: TimeModel,
             # root's hops start now; deeper hops start as relays land
             key = new_dsts[0][2]
             nbytes = new_dsts[0][1]
-            tree = broadcast_tree(src, [d for d, _, _ in new_dsts])
+            tree = _relay_tree(src, [d for d, _, _ in new_dsts])
             for parent, kids in tree.items():
                 if parent != src:
                     relay_children[(key, parent)] = kids

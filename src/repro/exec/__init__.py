@@ -9,25 +9,21 @@ from typing import Callable, Dict
 from .local import LocalExecutor                                # noqa: F401
 from .batched import (WaveExecutor, build_waves,                # noqa: F401
                       predict_wave_makespan)
-from .cluster import (ClusterExecutor,                          # noqa: F401
-                      predict_cluster_makespan)
-from .elastic import ChaosEvent, ElasticClusterExecutor         # noqa: F401
+from .cluster import predict_cluster_makespan                  # noqa: F401
+from .elastic import ChaosEvent, ClusterExecutor                # noqa: F401
 
 #: executor name -> zero-arg-capable factory (kwargs forwarded verbatim)
 EXECUTORS: Dict[str, Callable] = {
-    # per-task threaded executor (the correctness oracle's twin)
+    # per-task threaded NumPy executor (the correctness oracle's twin)
     "local": LocalExecutor,
-    # per-task with Pallas addmul tiles
-    "kernel": lambda **kw: LocalExecutor(use_pallas=True, **kw),
     # wave-batched stacked-kernel executor
     "batched": lambda **kw: WaveExecutor(backend="numpy", **kw),
     # wave-batched, ADDMUL groups through jax.vmap over the Pallas GEMM
     "batched-pallas": lambda **kw: WaveExecutor(backend="pallas", **kw),
-    # one process per ClusterSpec node, HEFT placements executed for real
+    # one process per ClusterSpec node, HEFT placements executed for
+    # real, under membership churn: heartbeats, lineage recovery,
+    # frontier re-planning, speculative straggler duplicates
     "cluster": ClusterExecutor,
-    # cluster execution under membership churn: heartbeats, lineage
-    # recovery, frontier re-planning, speculative straggler duplicates
-    "elastic": ElasticClusterExecutor,
 }
 
 

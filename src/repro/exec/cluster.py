@@ -1,34 +1,19 @@
-"""Multi-process cluster executor: HEFT node placements run for real.
+"""Worker runtime of the multi-process cluster executor.
 
-The paper's claim is that CMM "automatically configures communication and
-worker processes" so the schedule produced for a multi-node cluster
-actually executes across nodes.  The in-process executors
-(``exec/local.py``, ``exec/batched.py``) validate *numerics* but collapse
-the cluster to one address space and ignore the schedule's node
-assignments.  This backend closes the loop:
+The master loop lives in ``exec/elastic.py`` (``ClusterExecutor``); this
+module holds what runs inside each node's process and what the master
+needs to talk to it:
 
-* one **worker process per ClusterSpec node** (numpywren-style isolated
-  workers over shared storage), each running ``spec.workers_at(node)``
-  compute threads — heterogeneous specs (unequal worker counts/speeds per
-  node) spawn unequal pools, so ``plan()``'s placement decisions are
-  exercised, not just simulated;
-* every task runs **on the process of its HEFT-assigned node**
-  (``Schedule.placements``), driven by per-node dispatch queues;
-* tile buffers live in per-node ``multiprocessing.SharedMemory`` **tile
-  arenas** (one segment per live tile buffer, owned by the node that
-  produced it);
-* cross-node dependency edges become **XFER** operations: the consumer
-  node attaches the producer node's segment and copies the tile into its
-  own arena — a real inter-process copy, overlapped with compute (XFERs
-  run on the node's thread pool while other tiles execute);
-* one XFER per tile *version* per destination node — later consumers on
-  that node reuse the arrived copy, mirroring the §3.5 node-level cache
-  the scheduler planned with;
-* segments are **reference-counted** exactly like ``exec/local.py``'s
-  owned-bytes accounting: the master tracks static per-(node, tile) reader
-  counts (task inputs + accumulate-chain holds + outgoing XFER reads +
-  result-gather holds) and tells the owning node to free a segment as soon
-  as its last reader finishes.
+* ``_node_worker`` — one process per ClusterSpec node, running
+  ``spec.workers_at(node)`` compute threads fed by that node's dispatch
+  queue, so heterogeneous specs spawn unequal pools and HEFT's placement
+  decisions are executed, not just simulated;
+* ``_NodeArena`` — the node's tile arena: one
+  ``multiprocessing.SharedMemory`` segment per live tile buffer,
+  reference-counted, optionally bounded with a spill tier;
+* XFER copies — the consumer node attaches the producer node's segment
+  (``_attach_shm``) and copies the tile into its own arena, on the same
+  thread pool as compute, so transfers overlap in-flight tasks.
 
 Numerics: every task executes the same NumPy calls as ``LocalExecutor``
 and tile movement is bit-copying, so results are **bit-identical** to the
@@ -48,39 +33,30 @@ from __future__ import annotations
 import itertools
 import os
 import queue as _queue
-import shutil
 import threading
 import time
 import traceback
 import zlib
-from collections import defaultdict
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import replace
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 import numpy as np
 
 from ..core.fusion import eval_fused
 from ..core.graph import (TaskGraph, TaskKind, TileRef, matmul_epilogue,
                           matmul_flags)
-from ..core.heft import Schedule, edge_bytes
-from ..core.lazy import EWISE_FNS, Op, apply_scale, leaf_slice
-from ..core.machine import ClusterSpec, MemoryBudgetExceeded
+from ..core.heft import Schedule
+from ..core.lazy import EWISE_FNS, apply_scale, leaf_slice
+from ..core.machine import ClusterSpec
 from ..core.timemodel import TimeModel
 
-from ..core.tiling import assemble, tile_slices
+from ..core.tiling import tile_slices
 from ..runtime.spill import (AllocFailInjected, ArenaOverflow, SpillCorrupt,
                              SpillDataLost, SpillMiss, TileSpillStore,
                              run_spill_dir)
-from ..runtime.telemetry import (MetricsRegistry, Span, Tracer,
-                                 estimate_clock_offset)
-from ..runtime.wire import (BCAST_MIN_FANOUT, broadcast_tree,
-                            choose_wire_codec, decode_tile, encode_tile)
-
-#: chain-of-custody CRC audit (debug aid): when set, workers stamp a
-#: CRC32 on every tile custody transfer (task done, spill, unspill, XFER)
-#: and the master cross-checks each hop, printing the first corrupt stage
-_CRCAUDIT = bool(os.environ.get("CMM_CRCAUDIT"))
+from ..runtime.telemetry import Tracer
+from ..runtime.wire import decode_tile, encode_tile
 
 #: task kinds that accumulate into their output tile in place (the chain
 #: holds the buffer alive without listing it in ``ins`` — same bookkeeping
@@ -210,15 +186,13 @@ class _NodeArena:
         until dropped — eviction changes where bytes live, never values."""
         seg = self._segs.pop(ref)
         arr = self._arrs.pop(ref)
-        crc = (zlib.crc32(np.ascontiguousarray(arr).data) & 0xFFFFFFFF
-               if _CRCAUDIT else None)
         self._store().spill(ref, arr)
         self.cur -= seg.size
         self.evictions += 1
         del arr
         _release_seg(seg)
         if self._on_spill is not None:
-            self._on_spill(ref, crc)
+            self._on_spill(ref)
 
     def _ensure(self, nbytes: int, strict: bool = True) -> None:
         """Evict cold tiles until ``nbytes`` more fit the budget (lock
@@ -340,9 +314,7 @@ class _NodeArena:
         self.allocs += 1
         self.faults += 1
         if self._on_unspill is not None:
-            crc = (zlib.crc32(arr.data) & 0xFFFFFFFF
-                   if _CRCAUDIT else None)
-            self._on_unspill(ref, seg.name, arr.dtype.str, crc)
+            self._on_unspill(ref, seg.name, arr.dtype.str)
         return arr
 
     def get(self, ref: TileRef) -> np.ndarray:
@@ -622,7 +594,7 @@ def _node_worker(node: int, inq, outq, g: TaskGraph, tile, leaf_nodes,
 
     A bounded arena also serves XFER/gather *leases*: ``("hold", ref)``
     pins the tile (faulting it hot if cold) and acks ``("held", node,
-    ref, segname, dtype, crc)``; ``("release", ref)`` drops the pin.
+    ref, segname, dtype)``; ``("release", ref)`` drops the pin.
     Without the lease, a reader attaching the acked segment name races
     eviction — under pressure the LRU can cycle the whole arena inside
     the master→consumer round trip, so name-based retries livelock.
@@ -643,12 +615,11 @@ def _node_worker(node: int, inq, outq, g: TaskGraph, tile, leaf_nodes,
         except ImportError:             # pragma: no cover
             pass
 
-    def _on_spill(ref: TileRef, crc=None) -> None:
-        outq.put(("spill", node, ref, crc))
+    def _on_spill(ref: TileRef) -> None:
+        outq.put(("spill", node, ref))
 
-    def _on_unspill(ref: TileRef, segname: str, dtype_str: str,
-                    crc=None) -> None:
-        outq.put(("unspill", node, ref, segname, dtype_str, crc))
+    def _on_unspill(ref: TileRef, segname: str, dtype_str: str) -> None:
+        outq.put(("unspill", node, ref, segname, dtype_str))
 
     arena = _NodeArena(prefix, node, mem_bytes=mem_bytes,
                        spill_dir=spill_dir,
@@ -682,11 +653,8 @@ def _node_worker(node: int, inq, outq, g: TaskGraph, tile, leaf_nodes,
                 seg, dt = _execute_task(t, arena,
                                         ctx["leaf_nodes"], ctx["dtypes"],
                                         ctx["tile"], ctx["resident_ids"])
-            crc = None
-            if _CRCAUDIT and t.out is not None:
-                crc = zlib.crc32(arena.get(t.out).data) & 0xFFFFFFFF
             outq.put(("done", node, tid, seg, dt, pid,
-                      time.perf_counter() - t0, crc, tracer.drain()))
+                      time.perf_counter() - t0, tracer.drain()))
         except BaseException as e:
             if isinstance(e, SpillDataLost):
                 # the master must drop this holding BEFORE retrying the
@@ -748,7 +716,7 @@ def _node_worker(node: int, inq, outq, g: TaskGraph, tile, leaf_nodes,
                     remote.close()
             seg, dt = arena.seg_of(ref)
             outq.put(("xfer_done", node, version, ref, seg, dt,
-                      got if _CRCAUDIT else None, tracer.drain()))
+                      tracer.drain()))
         except BaseException:
             outq.put(("xfer_fail", node, version, ref,
                       traceback.format_exc()))
@@ -817,11 +785,9 @@ def _node_worker(node: int, inq, outq, g: TaskGraph, tile, leaf_nodes,
         name — the tile may have been faulted back in locally already."""
         arena.pin_all((ref,))
         try:
-            arr = arena.get(ref)
+            arena.get(ref)              # faults the tile hot
             seg, dt = arena.seg_of(ref)
-            crc = (zlib.crc32(arr.data) & 0xFFFFFFFF
-                   if _CRCAUDIT else None)
-            outq.put(("unspill", node, ref, seg, dt, crc))
+            outq.put(("unspill", node, ref, seg, dt))
         except KeyError:
             if ref in freed_refs:
                 # the master freed this ref after requesting the fault
@@ -865,11 +831,9 @@ def _node_worker(node: int, inq, outq, g: TaskGraph, tile, leaf_nodes,
                 ref = msg[1]
                 arena.pin_all((ref,))
                 try:
-                    arr = arena.get(ref)    # faults the tile hot if cold
+                    arena.get(ref)          # faults the tile hot if cold
                     seg, dt = arena.seg_of(ref)
-                    crc = (zlib.crc32(arr.data) & 0xFFFFFFFF
-                           if _CRCAUDIT else None)
-                    outq.put(("held", node, ref, seg, dt, crc))
+                    outq.put(("held", node, ref, seg, dt))
                 except KeyError:
                     arena.unpin_all((ref,))
                     if ref not in freed_refs:
@@ -941,821 +905,6 @@ def _node_worker(node: int, inq, outq, g: TaskGraph, tile, leaf_nodes,
     stats = arena.stats()
     arena.destroy()
     outq.put(("stats", node, stats, pid, tracer.drain()))
-
-
-class ClusterExecutor:
-    """Executes a planned tiled program across one process per cluster node,
-    honoring the HEFT schedule's per-task node placement.
-
-    ``workers_per_node`` overrides the per-node thread count (default:
-    ``spec.workers_at(node)``); ``free_buffers=False`` keeps every segment
-    alive until shutdown; ``mp_context`` picks the multiprocessing start
-    method (default ``fork`` where available — workers inherit the plan
-    instead of re-pickling it); ``timeout`` bounds each wait on worker
-    events so a dead worker raises instead of hanging.
-
-    ``session=True`` turns this into a session backend: the worker
-    processes (and their arenas, holding the session's retained tiles)
-    are spawned on the first ``execute()`` and SURVIVE across runs — each
-    run ships its graph to the workers via a ``("run", ...)`` op.
-    ``close_session()`` shuts the workers down and returns a per-node
-    arena audit (live/retained buffer counts for the session's refcount
-    audit).
-    """
-
-    def __init__(self, workers_per_node: Optional[int] = None,
-                 free_buffers: bool = True,
-                 mp_context: Optional[str] = None,
-                 timeout: float = 300.0,
-                 session: bool = False,
-                 timemodel: Optional[TimeModel] = None,
-                 wire_codec: Optional[str] = None,
-                 broadcast: bool = True,
-                 stream_gather: bool = True,
-                 trace: bool = True):
-        self.workers_per_node = workers_per_node
-        self.free_buffers = free_buffers
-        self.mp_context = mp_context
-        self.timeout = timeout
-        self.session = session
-        #: prices the per-edge codec choice (``choose_wire_codec``); with
-        #: no model the auto choice degrades to "raw"
-        self.timemodel = timemodel
-        #: None = auto (priced per edge); "raw"/"zlib" force one codec on
-        #: every cross-node XFER (conformance tests, benchmarks)
-        self.wire_codec = wire_codec
-        #: route fan-out edges through a relay tree instead of N unicasts
-        self.broadcast = broadcast
-        #: copy gathered result tiles out as their TAKECOPY lands instead
-        #: of barrier-waiting the whole run (time-to-first-tile).  Only
-        #: active while the master arena is unbounded — a bounded arena
-        #: could evict mid-attach, and the barrier path's lease already
-        #: handles that case.
-        self.stream_gather = stream_gather
-        #: flight recorder: on by default (obs_bench holds the paired
-        #: overhead under 5%); ``spans`` holds the last run's timeline
-        #: (master + ingested worker spans, master clock) after execute()
-        self.trace = trace
-        self.spans: List = []
-        self.stats: Dict[str, object] = {}
-        self._procs: Optional[List] = None
-        self._inqs: Optional[List] = None
-        self._outq = None
-        self._spec: Optional[ClusterSpec] = None
-        self._prefix = ""
-        self._broken = False
-
-    # -- driver --------------------------------------------------------------
-    def execute(self, plan) -> np.ndarray:
-        import multiprocessing as mp
-
-        g: TaskGraph = plan.program.graph
-        spec: Optional[ClusterSpec] = getattr(plan, "spec", None)
-        if spec is None:
-            raise ValueError("ClusterExecutor needs plan.spec "
-                             "(a ClusterSpec) to spawn node processes")
-        residency = getattr(plan, "residency", None)
-        from ..core.tiling import result_sets_of
-        rsets = result_sets_of(g)
-        if self.session and self._broken:
-            raise RuntimeError("session cluster executor is broken "
-                               "(a previous run failed); open a new session")
-        if self.session and self._spec is not None and self._spec != spec:
-            raise ValueError("a session cluster executor is bound to one "
-                             "ClusterSpec; plan was made for a different "
-                             "spec")
-        sched: Schedule = plan.schedule
-        node_of = {tid: p.node for tid, p in sched.placements.items()}
-        missing = [tid for tid in g.tasks if tid not in node_of]
-        if missing:
-            raise ValueError(f"schedule places {len(node_of)} tasks but the "
-                             f"graph has {len(g.tasks)}; unplaced: "
-                             f"{missing[:5]}")
-
-        method = self.mp_context or (
-            "fork" if "fork" in mp.get_all_start_methods() else "spawn")
-        ctx = mp.get_context(method)
-        prefix = f"cmm{os.getpid()}_{next(_RUN_IDS)}_"
-
-        # -- static dataflow: XFER endpoints, waiters, reader counts --------
-        xfer_by_producer: Dict[int, List[Tuple[int, int]]] = defaultdict(list)
-        for (p, _src, dst, nbytes) in sched.xfers(g):
-            xfer_by_producer[p].append((dst, nbytes))
-        # route each producer's fan-out through a relay tree (parent node
-        # -> child nodes); below the fan-out threshold the "tree" is the
-        # flat unicast star rooted at the producer's node
-        bcast_children: Dict[int, Dict[int, List[int]]] = {}
-        xfer_nbytes: Dict[int, int] = {}
-        for p, dsts in xfer_by_producer.items():
-            src = node_of[p]
-            xfer_nbytes[p] = dsts[0][1]
-            dstns = [d for (d, _nb) in dsts]
-            min_fanout = BCAST_MIN_FANOUT if self.broadcast \
-                else len(dstns) + 1
-            bcast_children[p] = broadcast_tree(src, dstns,
-                                               min_fanout=min_fanout)
-        waiters: Dict[Tuple[int, int], List[int]] = defaultdict(list)
-        xfers_left: Dict[int, int] = defaultdict(int)
-        reads: Dict[Tuple[int, TileRef], int] = defaultdict(int)
-        for t in g:
-            n = node_of[t.tid]
-            for r in t.ins:
-                reads[(n, r)] += 1
-            if t.kind in _CHAIN_KINDS and t.out is not None:
-                reads[(n, t.out)] += 1
-            for p in t.preds:
-                if node_of[p] != n and edge_bytes(g, g.tasks[p], t) > 0:
-                    waiters[(p, n)].append(t.tid)
-                    xfers_left[t.tid] += 1
-        # every relay hop reads its parent's copy: the parent's tile must
-        # stay alive until each child's copy lands (freed per-hop via
-        # dec_read at xfer_done)
-        for p, tree in bcast_children.items():
-            out = g.tasks[p].out
-            for parent, kids in tree.items():
-                reads[(parent, out)] += len(kids)
-        master_node = spec.master
-        # gather holds for takecopy'd roots; retention holds pin each
-        # persisted tile on its final producer's node so end-of-run
-        # refcount freeing can never free a tile the session retains
-        retained_refs: Dict[TileRef, Tuple[int, int]] = {}
-        for rs in rsets:
-            if rs.gather:
-                for r in rs.tiles:
-                    reads[(master_node, r)] += 1
-            else:
-                for r in rs.tiles:
-                    home = node_of[rs.producers[r]]
-                    reads[(home, r)] += 1
-                    retained_refs[r] = (rs.uid, home)
-        # streaming-gather targets: result tiles copied out as their
-        # TAKECOPY lands, overlapped with remaining compute.  Active only
-        # while the master arena is unbounded — the reads hold keeps each
-        # tile's segment alive until the streamed copy succeeds, so the
-        # lease-free attach cannot race a free (and the barrier path
-        # remains the fallback for anything not streamed)
-        stream_on = self.stream_gather and spec.mem_at(master_node) is None
-        gather_uid: Dict[TileRef, int] = {}
-        gvals: Dict[int, Dict[TileRef, np.ndarray]] = {}
-        for rs in rsets:
-            if rs.gather:
-                gvals[rs.uid] = {}
-                if stream_on:
-                    for r in rs.tiles:
-                        gather_uid[r] = rs.uid
-
-        # -- spawn one worker process per node (session: reuse) -------------
-        if self.session and self._procs is not None:
-            outq, inqs, procs = self._outq, self._inqs, self._procs
-            prefix = self._prefix
-        else:
-            outq = ctx.Queue()
-            inqs = [ctx.Queue() for _ in range(spec.n_nodes)]
-            procs = []
-            spill_dir = run_spill_dir(prefix)
-            for n in range(spec.n_nodes):
-                nthreads = self.workers_per_node or spec.workers_at(n)
-                args = (n, inqs[n], outq, None, None, None, None,
-                        nthreads, prefix) if self.session else \
-                    (n, inqs[n], outq, g, plan.tile,
-                     plan.program.leaf_nodes, plan.program.dtypes,
-                     nthreads, prefix)
-                args = args + (0.0, None, spec.mem_at(n), spill_dir,
-                               self.trace)
-                p = ctx.Process(target=_node_worker, args=args, daemon=True)
-                p.start()
-                procs.append(p)
-            if self.session:
-                self._procs, self._inqs, self._outq = procs, inqs, outq
-                self._spec, self._prefix = spec, prefix
-        if self.session:
-            # ship this run's context; RESIDENT leaves are resolved worker-
-            # side via their handle ids (the handles stay master-side)
-            worker_leafs = {uid: n for uid, n in
-                            plan.program.leaf_nodes.items()
-                            if n.op is not Op.RESIDENT}
-            rids = residency.resident_ids() if residency is not None else {}
-            run_msg = ("run", g, plan.tile, worker_leafs,
-                       plan.program.dtypes, rids)
-            for q in inqs:
-                q.put(run_msg)
-
-        seg_info: Dict[Tuple[int, TileRef], Tuple[str, str]] = {}
-        exec_nodes: Dict[int, int] = {}
-        node_pids: Dict[int, int] = {}
-        deps_left = {t.tid: len(t.preds) for t in g}
-        dispatched = set()
-        # unified metrics registry (replaces the ad-hoc counters dict):
-        # inc() is the atomic path, frozen_view() the read-only dict the
-        # stats consumers have always read
-        counters = MetricsRegistry()
-        for _k in ("xfers", "xfer_bytes", "wire_bytes",
-                   "xfers_compressed", "relay_hops",
-                   "gather_streamed_tiles"):
-            counters.inc(_k, 0)
-        # flight recorder: master-side tracer (node -1 = the master lane)
-        # plus per-node clock offsets from the NTP-style cal handshake —
-        # worker spans ingest onto the master timeline
-        tracer = Tracer(node=-1, enabled=self.trace)
-        clock_offsets: Dict[int, float] = {}
-        if self.trace:
-            for n in range(spec.n_nodes):
-                inqs[n].put(("cal", time.perf_counter()))
-        t_exec0 = time.perf_counter()
-        gather_t_first = [None]          # seconds to first gathered tile
-
-        def dec_read(n: int, r: TileRef) -> None:
-            if not self.free_buffers:
-                return
-            key = (n, r)
-            c = reads.get(key)
-            if c is None:
-                return
-            if c <= 1:
-                del reads[key]
-                spilled.discard(key)
-                fault_pending.discard(key)
-                parked_xfers.pop(key, None)
-                parked_packs.pop(key, None)
-                inqs[n].put(("free", r))
-            else:
-                reads[key] = c - 1
-
-        def maybe_dispatch(tid: int) -> None:
-            if tid in dispatched:
-                return
-            if deps_left[tid] == 0 and xfers_left[tid] == 0:
-                dispatched.add(tid)
-                inqs[node_of[tid]].put(("task", tid))
-
-        def next_event():
-            deadline = time.monotonic() + self.timeout
-            while True:
-                wait = min(10.0, max(0.1, deadline - time.monotonic()))
-                try:
-                    return outq.get(timeout=wait)
-                except _queue.Empty:
-                    dead = [i for i, p in enumerate(procs)
-                            if not p.is_alive()]
-                    if dead:
-                        raise RuntimeError(
-                            f"cluster worker process(es) {dead} died "
-                            f"(exit codes "
-                            f"{[procs[i].exitcode for i in dead]})")
-                    if time.monotonic() >= deadline:
-                        raise RuntimeError(
-                            f"cluster execution stalled: no worker event "
-                            f"within timeout={self.timeout}s")
-
-        total = len(g)
-        done = 0
-        phase = ["run"]
-        # -- spill-tier master state: where evicted tiles are, which
-        # fault-ins are outstanding, which XFERs wait on them
-        spilled: set = set()
-        fault_pending: set = set()
-        held_acks: set = set()
-        #: dispatched XFER attempts holding a source lease:
-        #: (version, dst) -> (lease node, codec) — the release/unpack
-        #: must go to the hop's actual source, which under a relay tree
-        #: is not necessarily the producer's node
-        leased_attempts: Dict[Tuple[int, int], Tuple[int, str]] = {}
-        #: (version, dst) -> hop source node (relay parent) for retries
-        #: and per-hop reader accounting
-        xfer_parent: Dict[Tuple[int, int], int] = {}
-        parked_xfers: Dict[Tuple[int, TileRef],
-                           List[Tuple[int, int]]] = defaultdict(list)
-        #: like parked_xfers but for the compressed (pack) lease path
-        parked_packs: Dict[Tuple[int, TileRef],
-                           List[Tuple[int, int, str]]] = defaultdict(list)
-        xfer_retries: Dict[Tuple[int, int], int] = defaultdict(int)
-        hold_retries: Dict[Tuple[int, TileRef], int] = defaultdict(int)
-        task_ao_retries: Dict[int, int] = defaultdict(int)
-        pending_retain: Dict[Tuple[int, int, int],
-                             Tuple[int, TileRef]] = {}
-        node_stats: Dict[int, Dict[str, int]] = {}
-        node_audits: Dict[int, Dict[str, int]] = {}
-
-        def request_fault(n: int, ref: TileRef) -> None:
-            spilled.add((n, ref))
-            if (n, ref) not in fault_pending:
-                fault_pending.add((n, ref))
-                inqs[n].put(("fault", ref))
-
-        cur_crc: Dict[Tuple[int, TileRef], int] = {}
-
-        def wire_codec_for(nbytes: int, src_n: int, dst_n: int) -> str:
-            if src_n == dst_n:
-                return "raw"
-            if self.wire_codec is not None:
-                return self.wire_codec
-            if self.timemodel is None:
-                return "raw"
-            return choose_wire_codec(nbytes, spec.bandwidth(src_n, dst_n),
-                                     self.timemodel)
-
-        def send_xfer(version: int, ref: TileRef, src_n: int, dst_n: int,
-                      retry: bool = False) -> None:
-            """Dispatch one XFER hop src_n -> dst_n of ``version``'s out
-            tile, choosing the priced wire codec per edge.  Compressed
-            hops and hops out of a bounded arena go through a source-side
-            lease (pack/hold); retries always lease."""
-            nbytes = xfer_nbytes.get(version, ref.bytes)
-            codec = wire_codec_for(nbytes, src_n, dst_n)
-            xfer_parent[(version, dst_n)] = src_n
-            if not retry:
-                counters.inc("xfers")
-                counters.inc("xfer_bytes", nbytes)
-                if src_n != node_of[version]:
-                    counters.inc("relay_hops")
-            if codec != "raw":
-                if not retry:
-                    counters.inc("xfers_compressed")
-                parked_packs[(src_n, ref)].append((version, dst_n, codec))
-                inqs[src_n].put(("pack", ref, codec))
-            elif retry or spec.mem_at(src_n) is not None:
-                # bounded source arena: dispatching the done message's
-                # segment name directly races eviction — lease the tile
-                # instead (pin on the source, released at xfer_done)
-                if not retry:
-                    counters.inc("wire_bytes", nbytes)
-                parked_xfers[(src_n, ref)].append((version, dst_n))
-                inqs[src_n].put(("hold", ref))
-            else:
-                counters.inc("wire_bytes", nbytes)
-                sname, sdt = seg_info[(src_n, ref)]
-                inqs[dst_n].put(("xfer", version, ref, sname, sdt))
-
-        def release_lease(version: int, dst_n: int, ref: TileRef) -> None:
-            ent = leased_attempts.pop((version, dst_n), None)
-            if ent is not None:
-                src_n, codec = ent
-                inqs[src_n].put(("release", ref) if codec == "raw"
-                                else ("unpack", ref))
-
-        def try_stream_gather(r: TileRef) -> None:
-            """Copy one landed result tile out during the main loop.  Any
-            failure falls back silently to the barrier gather (its reads
-            hold is only dropped on success)."""
-            uid = gather_uid.get(r)
-            if uid is None or r in gvals[uid]:
-                return
-            if (master_node, r) in spilled:
-                return
-            ent = seg_info.get((master_node, r))
-            if ent is None:             # pragma: no cover - defensive
-                return
-            try:
-                seg = _attach_shm(ent[0])
-            except FileNotFoundError:   # pragma: no cover - defensive
-                return
-            try:
-                view = np.ndarray(r.shape, dtype=np.dtype(ent[1]),
-                                  buffer=seg.buf)
-                val = view.copy()
-            finally:
-                seg.close()
-            if _CRCAUDIT:
-                crc_check("gather", master_node, r,
-                          zlib.crc32(val.data) & 0xFFFFFFFF)
-            gvals[uid][r] = val
-            counters.inc("gather_streamed_tiles")
-            if gather_t_first[0] is None:
-                gather_t_first[0] = time.perf_counter() - t_exec0
-            dec_read(master_node, r)
-
-        def crc_check(stage: str, n: int, ref: TileRef, crc) -> None:
-            if crc is None:
-                return
-            prev = cur_crc.get((n, ref))
-            if prev is not None and prev != crc:
-                import sys as _sys
-                line = (f"CRCAUDIT MISMATCH stage={stage} node={n} "
-                        f"ref={ref} prev={prev:#010x} now={crc:#010x}")
-                print(line, file=_sys.stderr, flush=True)
-            cur_crc[(n, ref)] = crc
-
-        def handle(msg) -> None:
-            nonlocal done
-            kind = msg[0]
-            if kind == "done":
-                _, n, tid, seg, dt, pid, _dur, *rest = msg
-                if len(rest) > 1:
-                    tracer.ingest(rest[1], clock_offsets.get(n, 0.0))
-                counters.observe("task_seconds", _dur)
-                t = g.tasks[tid]
-                if seg is not None and t.out is not None:
-                    seg_info[(n, t.out)] = (seg, dt)
-                    if rest and rest[0] is not None:
-                        # a task legitimately (re)writes its out tile
-                        cur_crc[(n, t.out)] = rest[0]
-                exec_nodes[tid] = n
-                node_pids[n] = pid
-                done += 1
-                # root hops of the (possibly flat) relay tree; deeper
-                # hops start as each relay's copy lands (xfer_done)
-                for child in bcast_children.get(tid, {}).get(n, ()):
-                    send_xfer(tid, t.out, n, child)
-                for s in sorted(t.succs):
-                    deps_left[s] -= 1
-                    maybe_dispatch(s)
-                for r in t.ins:
-                    dec_read(n, r)
-                if t.kind in _CHAIN_KINDS and t.out is not None:
-                    dec_read(n, t.out)
-                if t.kind is TaskKind.TAKECOPY and n == master_node \
-                        and phase[0] == "run":
-                    try_stream_gather(t.out)
-            elif kind == "xfer_done":
-                _, n, version, ref, seg, dt, *rest = msg
-                if len(rest) > 1:
-                    tracer.ingest(rest[1], clock_offsets.get(n, 0.0))
-                seg_info[(n, ref)] = (seg, dt)
-                # the copy landed: release the hop source's lease
-                release_lease(version, n, ref)
-                hop_src = xfer_parent.pop((version, n), node_of[version])
-                if rest and rest[0] is not None:
-                    src_crc = cur_crc.get((hop_src, ref))
-                    if src_crc is not None and src_crc != rest[0]:
-                        import sys as _sys
-                        print(f"CRCAUDIT MISMATCH stage=xfer "
-                              f"src={hop_src} dst={n} ref={ref} "
-                              f"src_crc={src_crc:#010x} "
-                              f"dst_crc={rest[0]:#010x}",
-                              file=_sys.stderr, flush=True)
-                    cur_crc[(n, ref)] = rest[0]
-                dec_read(hop_src, g.tasks[version].out)
-                # the landed copy relays onward to its broadcast children
-                for child in bcast_children.get(version, {}).get(n, ()):
-                    send_xfer(version, ref, n, child)
-                for s in waiters.pop((version, n), ()):
-                    xfers_left[s] -= 1
-                    maybe_dispatch(s)
-            elif kind == "spill":
-                spilled.add((msg[1], msg[2]))
-                if len(msg) > 3:
-                    crc_check("spill", msg[1], msg[2], msg[3])
-            elif kind == "unspill":
-                _, n, ref, sname, dt, *rest = msg
-                if rest:
-                    crc_check("unspill", n, ref, rest[0])
-                seg_info[(n, ref)] = (sname, dt)
-                spilled.discard((n, ref))
-                fault_pending.discard((n, ref))
-            elif kind == "held":
-                # source-side lease granted: the segment name is pinned
-                # until the matching "release", so parked XFERs can
-                # attach it without racing eviction
-                _, n, ref, sname, dt, *rest = msg
-                if rest:
-                    crc_check("held", n, ref, rest[0])
-                seg_info[(n, ref)] = (sname, dt)
-                spilled.discard((n, ref))
-                fault_pending.discard((n, ref))
-                held_acks.add((n, ref))
-                hold_retries.pop((n, ref), None)
-                for (version, dstn) in parked_xfers.pop((n, ref), ()):
-                    leased_attempts[(version, dstn)] = (n, "raw")
-                    inqs[dstn].put(("xfer", version, ref, sname, dt))
-            elif kind == "packed":
-                # compressed-wire lease granted: the staging segment
-                # holds the encoded payload, pinned until "unpack"
-                _, n, ref, sname, dt, codec, comp_nbytes, raw_crc = msg
-                hold_retries.pop((n, ref), None)
-                for (version, dstn, _c) in parked_packs.pop((n, ref), ()):
-                    counters.inc("wire_bytes", comp_nbytes)
-                    leased_attempts[(version, dstn)] = (n, codec)
-                    inqs[dstn].put(("xfer", version, ref, sname, dt,
-                                    codec, comp_nbytes, raw_crc))
-            elif kind == "hold_fail":
-                # transient source-side overflow faulting the tile hot:
-                # re-send the hold/pack — each round trip is natural
-                # backoff while in-flight tasks drain their pins
-                _, n, ref = msg
-                hold_retries[(n, ref)] += 1
-                if hold_retries[(n, ref)] > 100:
-                    raise MemoryBudgetExceeded(
-                        n, 0, spec.mem_at(n) or 0,
-                        msg=f"node {n} could not fault {ref} hot for an "
-                            f"XFER/gather lease after "
-                            f"{hold_retries[(n, ref)]} attempts (arena "
-                            f"persistently full of pinned tiles)")
-                if parked_packs.get((n, ref)):
-                    inqs[n].put(("pack", ref, parked_packs[(n, ref)][0][2]))
-                else:
-                    inqs[n].put(("hold", ref))
-            elif kind == "tile_lost":
-                # static membership has no lineage machinery to recompute
-                # a lost intermediate — structured failure, not an OOM
-                raise RuntimeError(
-                    f"spilled tile {msg[2]} lost on node {msg[1]} "
-                    f"(missing/corrupt spill file); the static cluster "
-                    f"executor cannot lineage-recompute — use the elastic "
-                    f"executor for graceful degradation:\n{msg[3]}")
-            elif kind == "retained":
-                _, n, key, sname, dt = msg
-                ent = pending_retain.pop(key, None)
-                if ent is not None:
-                    uid, r = ent
-                    residency.retain_seg(uid, r.i, r.j, n, sname, dt)
-            elif kind == "audit":
-                node_audits[msg[1]] = msg[2]
-            elif kind == "stats":
-                node_stats[msg[1]] = msg[2]
-                node_pids.setdefault(msg[1], msg[3])
-                if len(msg) > 4:
-                    tracer.ingest(msg[4], clock_offsets.get(msg[1], 0.0))
-            elif kind == "cal":
-                # worker's clock echo: NTP-style midpoint offset, under
-                # which worker span timestamps map onto the master clock
-                clock_offsets[msg[1]] = estimate_clock_offset(
-                    msg[2], msg[3], time.perf_counter())
-            elif kind == "error":
-                if "ArenaOverflow" in msg[3]:
-                    # often transient: concurrent tasks' pinned inputs
-                    # drain as they complete — bounded re-dispatch (the
-                    # failure is pre-mutation, so chains are safe too)
-                    if msg[2] >= 0:
-                        task_ao_retries[msg[2]] += 1
-                        if task_ao_retries[msg[2]] <= 3:
-                            inqs[msg[1]].put(("task", msg[2]))
-                            return
-                    raise MemoryBudgetExceeded(
-                        msg[1], 0, spec.mem_at(msg[1]) or 0,
-                        msg=f"node {msg[1]} arena overflow (budget "
-                            f"{spec.mem_at(msg[1])} bytes, nothing left "
-                            f"to evict) during {phase[0]}:\n{msg[3]}")
-                raise RuntimeError(
-                    f"cluster task failed on node {msg[1]} "
-                    f"(task {msg[2]}) during {phase[0]}:\n{msg[3]}")
-            elif kind == "xfer_fail":
-                _, dstn, version, ref, tb = msg
-                # static membership: recoverable causes are the source
-                # segment having been spilled between the producer's done
-                # and the consumer's attach, or a transient destination
-                # arena overflow — re-request through a source fault-in
-                # (its ack round-trip doubles as backoff), bounded;
-                # anything else is a broken run
-                src = xfer_parent.get((version, dstn), node_of[version])
-                # the failed attempt's lease is still held — drop it
-                # (the retry takes a fresh one)
-                release_lease(version, dstn, ref)
-                xfer_retries[(version, dstn)] += 1
-                if xfer_retries[(version, dstn)] > 3:
-                    if "ArenaOverflow" in tb:
-                        raise MemoryBudgetExceeded(
-                            dstn, 0, spec.mem_at(dstn) or 0,
-                            msg=f"node {dstn} arena overflow receiving "
-                                f"XFER of {ref}:\n{tb}")
-                    raise RuntimeError(
-                        f"cluster XFER of {ref} (version {version}) "
-                        f"failed on node {dstn} after "
-                        f"{xfer_retries[(version, dstn)]} attempts:\n{tb}")
-                send_xfer(version, ref, src, dstn, retry=True)
-
-        try:
-            for t in g.sources():
-                maybe_dispatch(t.tid)
-            while done < total:
-                handle(next_event())
-
-            # -- gather result tiles from the master node's arena ----------
-            outs: List[np.ndarray] = []
-            gather_bytes = 0
-            retained = 0
-            phase[0] = "gather"
-            gather_span_t0 = time.perf_counter()
-            for rs in rsets:
-                if not rs.gather:
-                    continue
-                vals: Dict[TileRef, np.ndarray] = gvals.get(rs.uid, {})
-                for r in rs.tiles:
-                    if r in vals:       # already streamed mid-run
-                        gather_bytes += r.bytes
-                        continue
-                    leased = spec.mem_at(master_node) is not None
-                    if leased:
-                        # lease the tile hot for the attach (same race
-                        # as XFER sources: the worker keeps allocating
-                        # while we read)
-                        held_acks.discard((master_node, r))
-                        inqs[master_node].put(("hold", r))
-                        while (master_node, r) not in held_acks:
-                            handle(next_event())
-                    try:
-                        for _attempt in range(5):
-                            if (master_node, r) in spilled:
-                                request_fault(master_node, r)
-                                while (master_node, r) in spilled:
-                                    handle(next_event())
-                            sname, dt = seg_info[(master_node, r)]
-                            try:
-                                seg = _attach_shm(sname)
-                            except FileNotFoundError:
-                                # evicted between unspill and attach
-                                request_fault(master_node, r)
-                                continue
-                            try:
-                                view = np.ndarray(r.shape,
-                                                  dtype=np.dtype(dt),
-                                                  buffer=seg.buf)
-                                vals[r] = view.copy()
-                            finally:
-                                seg.close()
-                            if _CRCAUDIT:
-                                crc_check(
-                                    "gather", master_node, r,
-                                    zlib.crc32(vals[r].data) & 0xFFFFFFFF)
-                            break
-                        else:
-                            raise RuntimeError(
-                                f"could not gather result tile {r}: "
-                                f"segment kept vanishing under memory "
-                                f"pressure")
-                    finally:
-                        if leased:
-                            inqs[master_node].put(("release", r))
-                    gather_bytes += r.bytes
-                    if gather_t_first[0] is None:
-                        gather_t_first[0] = time.perf_counter() - t_exec0
-                    dec_read(master_node, r)
-                outs.append(assemble(vals, rs.shape, plan.tile, rs.uid))
-
-            gather_t_full = time.perf_counter() - t_exec0
-            if self.trace:
-                # one master-lane span for the (barrier) gather phase, so
-                # the trace shows result assembly against worker compute
-                tracer.add(Span("GATHER", "GATHER", -1, 0, gather_span_t0,
-                                time.perf_counter() - gather_span_t0,
-                                {"bytes": gather_bytes}))
-
-            # -- retention: persisted tiles move to the session store -------
-            phase[0] = "retention"
-            for r, (uid, home) in retained_refs.items():
-                h = residency.retain[uid]
-                pending_retain[(h.hid, r.i, r.j)] = (uid, r)
-                inqs[home].put(("retain", r, (h.hid, r.i, r.j)))
-                retained += 1
-
-            # -- orderly shutdown + per-node stats --------------------------
-            if self.session:
-                # workers survive; audit instead of stop (per-worker FIFO
-                # means the audit reply confirms every retain op above was
-                # processed — its ack handled on the way)
-                for q in inqs:
-                    q.put(("audit",))
-                while len(node_audits) < spec.n_nodes:
-                    handle(next_event())
-                while pending_retain:       # pragma: no cover - FIFO order
-                    handle(next_event())
-                node_stats = node_audits
-            else:
-                for q in inqs:
-                    q.put(("stop",))
-                while len(node_stats) < spec.n_nodes:
-                    handle(next_event())
-                for p in procs:
-                    p.join(timeout=self.timeout)
-        except BaseException:
-            self._broken = True
-            for p in procs:
-                if p.is_alive():
-                    p.terminate()
-            # best-effort unlink of segments the (terminated) workers own;
-            # tracker register/unregister are silenced — these names were
-            # registered by the workers' trackers, not the master's
-            from multiprocessing import resource_tracker, shared_memory
-            with _TRACK_LOCK:
-                orig = (resource_tracker.register,
-                        resource_tracker.unregister)
-                resource_tracker.register = lambda *a, **kw: None
-                resource_tracker.unregister = lambda *a, **kw: None
-                try:
-                    names = {sname for (sname, _dt) in seg_info.values()}
-                    if os.path.isdir("/dev/shm"):
-                        # segments allocated but not yet reported when the
-                        # workers were terminated are only findable by the
-                        # run's namespace prefix
-                        names.update(f for f in os.listdir("/dev/shm")
-                                     if f.startswith(prefix))
-                    for sname in names:
-                        try:
-                            _release_seg(
-                                shared_memory.SharedMemory(name=sname))
-                        except FileNotFoundError:
-                            pass
-                finally:
-                    (resource_tracker.register,
-                     resource_tracker.unregister) = orig
-            shutil.rmtree(run_spill_dir(prefix), ignore_errors=True)
-            raise
-        finally:
-            if not self.session or self._broken:
-                for p in procs:
-                    if p.is_alive():        # pragma: no cover
-                        p.terminate()
-                        p.join(timeout=5)
-
-        leaked_spill = 0
-        if not self.session:
-            # after a clean non-session stop every spill file must be gone;
-            # leftovers are leaks (counted, then reaped)
-            sd = run_spill_dir(prefix)
-            try:
-                leaked_spill = len(os.listdir(sd))
-            except OSError:
-                leaked_spill = 0
-            shutil.rmtree(sd, ignore_errors=True)
-
-        # the registry's frozen_view IS the stats dict consumers always
-        # read — counters stay inside the registry, run-shaped facts ride
-        # along as extras
-        self.spans = tracer.drain()
-        self.stats = counters.frozen_view({
-            "tasks_run": total,
-            "workers": sum(self.workers_per_node or spec.workers_at(n)
-                           for n in range(spec.n_nodes)),
-            "nodes": spec.n_nodes,
-            "gather_bytes": gather_bytes,
-            "gather_first_tile_s": gather_t_first[0],
-            "gather_full_result_s": gather_t_full,
-            # must be 0 after a clean run: an open lease is a stranded
-            # source pin on some worker's (possibly bounded) arena
-            "stale_leases": len(leased_attempts)
-            + sum(len(v) for v in parked_xfers.values())
-            + sum(len(v) for v in parked_packs.values()),
-            "retained_tiles": retained,
-            "peak_buffer_bytes": sum(s["peak_buffer_bytes"]
-                                     for s in node_stats.values()),
-            "cur_buffer_bytes": sum(s["cur_buffer_bytes"]
-                                    for s in node_stats.values()),
-            "buffers_freed": sum(s["buffers_freed"]
-                                 for s in node_stats.values()),
-            "live_buffers": sum(s.get("live_buffers", 0)
-                                for s in node_stats.values()),
-            "retained_total": sum(s.get("retained", 0)
-                                  for s in node_stats.values()),
-            "evictions": sum(s.get("evictions", 0)
-                             for s in node_stats.values()),
-            "faults": sum(s.get("faults", 0)
-                          for s in node_stats.values()),
-            "spill_writes": sum(s.get("spill_writes", 0)
-                                for s in node_stats.values()),
-            "spill_reads": sum(s.get("spill_reads", 0)
-                               for s in node_stats.values()),
-            "spilled_bytes": sum(s.get("spilled_bytes", 0)
-                                 for s in node_stats.values()),
-            "leaked_spill_files": leaked_spill,
-            "exec_nodes": exec_nodes,
-            "node_pids": node_pids,
-        })
-        if not outs:
-            return None
-        return outs[0] if len(outs) == 1 else outs
-
-    # -- session lifecycle ----------------------------------------------------
-    def drop_retained(self, node: int, key) -> None:
-        """Session free path: drop one retained tile from ``node``'s arena."""
-        if self._inqs is not None and not self._broken:
-            self._inqs[node].put(("drop", key))
-
-    def close_session(self) -> Dict[int, Dict[str, int]]:
-        """Stop the long-lived workers; returns the per-node arena stats
-        collected at shutdown (live/retained buffer counts — the session's
-        refcount audit input)."""
-        audit: Dict[int, Dict[str, int]] = {}
-        if self._procs is None:
-            return audit
-        if not self._broken:
-            for q in self._inqs:
-                q.put(("stop",))
-            deadline = time.monotonic() + min(self.timeout, 30.0)
-            while len(audit) < len(self._procs) and \
-                    time.monotonic() < deadline:
-                try:
-                    msg = self._outq.get(timeout=0.5)
-                except _queue.Empty:
-                    if all(not p.is_alive() for p in self._procs):
-                        break
-                    continue
-                if msg[0] == "stats":
-                    audit[msg[1]] = msg[2]
-        for p in self._procs:
-            p.join(timeout=5)
-            if p.is_alive():                     # pragma: no cover
-                p.terminate()
-        self._procs = self._inqs = self._outq = None
-        # spill-file leak sweep: a clean shutdown leaves the run's spill
-        # directory empty — report leftovers so the session audit can fail
-        if self._prefix:
-            sd = run_spill_dir(self._prefix)
-            try:
-                leaked = len(os.listdir(sd))
-            except OSError:
-                leaked = 0
-            shutil.rmtree(sd, ignore_errors=True)
-            audit["spill"] = {"leaked_spill_files": leaked}
-        return audit
 
 
 #: unique per-execute() shm namespace within this master process

@@ -1,12 +1,14 @@
-"""Elastic fault-tolerant cluster executor: plan -> observe -> re-plan.
+"""Multi-process cluster executor: HEFT placements run for real, and
+survive membership churn (plan -> observe -> re-plan).
 
-``ClusterExecutor`` (exec/cluster.py) executes a HEFT schedule across one
-worker process per node, but the membership is frozen at plan time: a
-dead worker hangs the run and a node joining mid-run is invisible.  This
-backend is the paper's dynamic-cluster story made real — "automatic
-configuration of communication and worker processes ... automatically
-scale up for clusters of heterogeneous nodes" — implemented as a master
-control loop over three mechanisms:
+The paper's claim is that CMM "automatically configures communication and
+worker processes" so the schedule produced for a multi-node cluster
+actually executes across nodes, and "automatically scale[s] up for
+clusters of heterogeneous nodes".  ``ClusterExecutor`` runs one worker
+process per ClusterSpec node (the runtime in ``exec/cluster.py``): every
+task runs on the process of its HEFT-assigned node, tiles live in per-node
+shared-memory arenas, and cross-node edges are real inter-process XFER
+copies.  The master is a control loop over three mechanisms:
 
 * **membership** (``runtime/membership.py``): workers heartbeat over
   their queues; process exit or heartbeat staleness raises a DEATH
@@ -35,8 +37,7 @@ tiles, so results under any failure/join/straggle interleaving are
 **bit-identical to** ``LocalExecutor`` — the repo's conformance bar —
 which the fault-injection tier (tests/test_elastic.py) asserts.
 
-Unlike the static executor's pre-computed transfer plan, the elastic
-master routes data dynamically: it tracks which *version* (producer
+The master routes data dynamically: it tracks which *version* (producer
 task id) of each tile ref is bound in each node's arena, and requests a
 shared-memory XFER from a live holder whenever a dispatch-ready task is
 missing an input at its assigned node.  Writes to one ``(node, ref)``
@@ -120,12 +121,10 @@ class ChaosEvent:
     alloc_fail_nth: int = 1
 
 
-class ElasticClusterExecutor:
-    """Multi-process cluster executor that survives membership churn.
-
-    Same numerics and tile runtime as ``ClusterExecutor`` (one process
-    per node, shared-memory arenas, real XFER copies), plus the elastic
-    control plane described in the module docstring.  ``timemodel``
+class ClusterExecutor:
+    """Executes a planned tiled program across one process per cluster
+    node, honouring the HEFT schedule's per-task node placement, under
+    the control plane described in the module docstring.  ``timemodel``
     drives frontier re-planning (``CMMEngine.run`` injects its own);
     ``membership`` tunes detection latency; ``chaos`` injects
     failures/joins/stragglers for tests and the chaos benchmark;
@@ -264,13 +263,13 @@ class ElasticClusterExecutor:
         g: TaskGraph = plan.program.graph
         spec: Optional[ClusterSpec] = getattr(plan, "spec", None)
         if spec is None:
-            raise ValueError("ElasticClusterExecutor needs plan.spec")
+            raise ValueError("ClusterExecutor needs plan.spec")
         if self.session and self._broken:
-            raise RuntimeError("session elastic executor is broken "
+            raise RuntimeError("session cluster executor is broken "
                                "(a previous run failed); open a new session")
         if self.session and self._started and spec != self._cur_spec:
             raise ValueError(
-                "session elastic executor: the plan's spec does not match "
+                "session cluster executor: the plan's spec does not match "
                 "the membership-adjusted current_spec; re-plan against "
                 "executor.current_spec")
         residency = getattr(plan, "residency", None)
@@ -1068,9 +1067,8 @@ class ElasticClusterExecutor:
             idle-but-alive workers must still trip the stall watchdog)."""
             kind = msg[0]
             if kind == "done":
-                _, n, tid, seg, dt, pid, dur, *_rest = msg
-                if len(_rest) > 1:
-                    tracer.ingest(_rest[1], offsets.get(n, 0.0))
+                _, n, tid, seg, dt, pid, dur, spans = msg
+                tracer.ingest(spans, offsets.get(n, 0.0))
                 cnt.observe("task_seconds", dur)
                 ms.record_task(n, dur)
                 node_pids[n] = pid
@@ -1125,9 +1123,8 @@ class ElasticClusterExecutor:
                     run_gc()
                 fire_chaos()
             elif kind == "xfer_done":
-                _, n, version, ref, seg, dt, *_rest = msg
-                if len(_rest) > 1:
-                    tracer.ingest(_rest[1], offsets.get(n, 0.0))
+                _, n, version, ref, seg, dt, spans = msg
+                tracer.ingest(spans, offsets.get(n, 0.0))
                 write_busy.discard((n, ref))
                 ent = xfer_inflight.pop((n, ref), None)
                 if ent is not None and (ent[1], ref) in src_busy:
@@ -1174,7 +1171,7 @@ class ElasticClusterExecutor:
             elif kind == "held":
                 # one lease pin granted (the hold may have faulted the
                 # tile hot under a fresh segment name — rebind it)
-                _, n, ref, seg, dt, *_rest = msg
+                _, n, ref, seg, dt = msg
                 ent0 = avail.get((n, ref))
                 if ent0 is not None:
                     avail[(n, ref)] = (ent0[0], seg, dt)
@@ -1210,7 +1207,7 @@ class ElasticClusterExecutor:
             elif kind == "spill":
                 spilled.add((msg[1], msg[2]))
             elif kind == "unspill":
-                _, n, ref, sname, dt, *_rest = msg
+                _, n, ref, sname, dt = msg
                 ent = avail.get((n, ref))
                 if ent is not None:
                     # the fault-in rebinds under a fresh segment name;
@@ -1290,7 +1287,7 @@ class ElasticClusterExecutor:
                                 f"nothing left to evict) running task "
                                 f"{tid}, attempt {tries}:\n{msg[3]}")
                     raise RuntimeError(
-                        f"elastic task failed on node {msg[1]} "
+                        f"cluster task failed on node {msg[1]} "
                         f"(task {tid}, attempt {tries}):\n{msg[3]}")
                 if t.out is not None:
                     write_busy.discard((msg[1], t.out))
@@ -1301,7 +1298,7 @@ class ElasticClusterExecutor:
                     self._mcfg.retry_backoff_s * (2 ** (tries - 1)), 2.0)
                 if deps_left[tid] == 0 and not dispatched[tid]:
                     ready.add(tid)
-            elif kind == "stats":
+            elif kind in ("stats", "audit"):
                 self._node_stats[msg[1]] = msg[2]
                 if len(msg) > 4:
                     tracer.ingest(msg[4], offsets.get(msg[1], 0.0))
@@ -1445,7 +1442,7 @@ class ElasticClusterExecutor:
                     last_progress = now
                 elif now - last_progress > self.timeout:
                     raise RuntimeError(
-                        f"elastic execution stalled: no progress within "
+                        f"cluster execution stalled: no progress within "
                         f"timeout={self.timeout}s "
                         f"({len(completed)}/{total} tasks, "
                         f"ready={sorted(ready)[:8]})")
@@ -1600,6 +1597,16 @@ class ElasticClusterExecutor:
                     p = self._procs.get(n)
                     if p is not None:
                         p.join(timeout=5)
+            else:
+                # workers survive: audit their arenas instead (per-worker
+                # FIFO: each reply follows this run's frees and retains)
+                expect = [n for n in ms.alive_nodes()
+                          if self._inqs.get(n) is not None]
+                for n in expect:
+                    self._inqs[n].put(("audit",))
+                pump_until(lambda: all(n in self._node_stats
+                                       or not alive(n) for n in expect),
+                           "arena audits")
         except ResidentTilesLost:
             # orderly abort: workers (and their retained arenas) survive
             # for the session's lineage recompute + retry
@@ -1634,6 +1641,8 @@ class ElasticClusterExecutor:
         self.spans = tracer.drain()
         self.stats = cnt.frozen_view({
             "tasks_run": total,
+            # node arenas the audit sums (alive at the end of the run)
+            "nodes": len(self._node_stats),
             "nodes_initial": spec.n_nodes,
             "nodes_final": len(ms.alive_nodes()),
             "workers": sum(cur_spec.workers_at(n)
@@ -1658,6 +1667,12 @@ class ElasticClusterExecutor:
                                      for s in self._node_stats.values()),
             "cur_buffer_bytes": sum(s["cur_buffer_bytes"]
                                     for s in self._node_stats.values()),
+            # both 0 after a clean one-shot run; in session mode
+            # retained_total counts the session's tiles in the arenas
+            "live_buffers": sum(s.get("live_buffers", 0)
+                                for s in self._node_stats.values()),
+            "retained_total": sum(s.get("retained", 0)
+                                  for s in self._node_stats.values()),
             "evictions": sum(s.get("evictions", 0)
                              for s in self._node_stats.values()),
             "faults": sum(s.get("faults", 0)

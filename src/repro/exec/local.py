@@ -1,4 +1,5 @@
-"""Local executor: runs a planned tiled task graph and materialises the result.
+"""Local executor: runs a planned tiled task graph with NumPy and
+materialises the result.
 
 Executes tasks in HEFT-priority order with a worker pool sized from the
 plan's machine model (``ClusterSpec.worker_procs`` x nodes, falling back to
@@ -21,9 +22,6 @@ Zero-copy tile runtime:
 * No global buffer lock: each buffer has exactly one writer at a time (the
   dependency edges guarantee it), so writes go straight into the dict;
   only the tiny refcount/scheduler bookkeeping is serialised.
-
-``use_pallas=True`` routes ``addmul`` tiles through the Pallas blocked-GEMM
-kernel (interpret mode on CPU, compiled on TPU).
 """
 from __future__ import annotations
 
@@ -43,10 +41,9 @@ from ..runtime.telemetry import Tracer
 
 
 class LocalExecutor:
-    def __init__(self, workers: Optional[int] = None, use_pallas: bool = False,
+    def __init__(self, workers: Optional[int] = None,
                  free_buffers: bool = True, trace: bool = True):
         self.workers = workers
-        self.use_pallas = use_pallas
         self.free_buffers = free_buffers
         #: flight recorder: EXEC spans per task (node 0, one lane per
         #: pool thread); ``spans`` holds the last run's timeline
@@ -86,12 +83,9 @@ class LocalExecutor:
         mem = {"cur": 0, "peak": 0, "freed": 0}
         #: bytes currently accounted per tile ref — a task that REBINDS
         #: ``buffers[t.out]`` over an earlier allocation (ufunc output over
-        #: a CALLOC'd tile, the Pallas addmul result) must release the old
+        #: a CALLOC'd tile, a fused epilogue's result) must release the old
         #: allocation's bytes, or ``peak_buffer_bytes`` drifts upward
         owned: Dict[TileRef, int] = {}
-
-        if self.use_pallas:
-            from ..kernels import ops as kops
 
         def run_task(t: Task):
             if t.kind is TaskKind.CALLOC:
@@ -117,25 +111,12 @@ class LocalExecutor:
                 a = a.T if ta else a
                 b = b.T if tb else b
                 c = buffers[t.out]
-                if self.use_pallas:
-                    if epi is not None:
-                        buffers[t.out] = np.asarray(kops.addmul(
-                            c, np.ascontiguousarray(a),
-                            np.ascontiguousarray(b),
-                            epilogue=epi,
-                            extras=[np.ascontiguousarray(buffers[r])
-                                    for r in t.ins[2:]]))
-                    else:
-                        buffers[t.out] = np.asarray(
-                            kops.addmul(c, np.ascontiguousarray(a),
-                                        np.ascontiguousarray(b)))
-                else:
-                    c += a @ b
-                    if epi is not None:
-                        # last task of the k-chain: apply the fused
-                        # elementwise epilogue over the accumulated tile
-                        buffers[t.out] = eval_fused(
-                            epi, [c] + [buffers[r] for r in t.ins[2:]])
+                c += a @ b
+                if epi is not None:
+                    # last task of the k-chain: apply the fused
+                    # elementwise epilogue over the accumulated tile
+                    buffers[t.out] = eval_fused(
+                        epi, [c] + [buffers[r] for r in t.ins[2:]])
                 return
             if t.kind is TaskKind.ADD:
                 buffers[t.out] = buffers[t.ins[0]] + buffers[t.ins[1]]

@@ -22,24 +22,24 @@ instrumented path (ROADMAP item 5).  Three pieces live here:
   recalibration.  With the default priors (``compress_bandwidth == 0``)
   the codec is disabled and every decision degrades to ``"raw"``.
 
-* **Broadcast relay trees** — one-producer-many-consumer edges (common
-  after ``persist()``) are served by a deterministic binary relay tree
-  over ``[src] + sorted(dsts)`` instead of N unicasts, halving the
-  source's serialized send time per doubling of fan-out.  The same
-  ``broadcast_tree`` shape is used by the executors *and* the
-  simulator so ``engine`` auto-selection prices what actually runs.
+* **Broadcast relays** — one-producer-many-consumer edges (common after
+  ``persist()``) of at least ``BCAST_MIN_FANOUT`` destinations are
+  served by relays instead of N unicasts: the cluster executor lets each
+  holder serve at most ``BCAST_MIN_FANOUT - 1`` readers at a time, so
+  landed copies serve the rest, and the simulator prices that as a
+  binary relay tree.
 """
 from __future__ import annotations
 
 import zlib
-from typing import Dict, List, Sequence, Tuple
+from typing import Dict, Tuple
 
 import numpy as np
 
 __all__ = [
     "WireCodec", "RawCodec", "ZlibCodec", "CODECS", "get_codec",
     "encode_tile", "decode_tile", "choose_wire_codec", "wire_seconds",
-    "predicted_xfer_seconds", "broadcast_tree", "BCAST_MIN_FANOUT",
+    "predicted_xfer_seconds", "BCAST_MIN_FANOUT",
 ]
 
 #: minimum cross-node destination count before a relay tree beats
@@ -188,28 +188,3 @@ def predicted_xfer_seconds(nbytes: int, tm, codec: str = "raw",
     if cbw > 0:
         t += nbytes / cbw
     return t
-
-
-def broadcast_tree(src: int, dsts: Sequence[int],
-                   min_fanout: int = BCAST_MIN_FANOUT,
-                   ) -> Dict[int, List[int]]:
-    """Deterministic binary relay tree for one fan-out edge.
-
-    Maps each relay node to its children over ``[src] + sorted(dsts)``
-    (node at position ``i`` feeds positions ``2i+1`` and ``2i+2``).
-    Below ``min_fanout`` destinations the "tree" is the flat N-unicast
-    star rooted at ``src`` — a tree of depth one.  The executors follow
-    this shape when routing XFERs and the simulator follows it when
-    pricing them, so the model and the machine agree on every hop.
-    """
-    order = [src] + sorted(set(int(d) for d in dsts) - {src})
-    tree: Dict[int, List[int]] = {}
-    if len(order) - 1 < min_fanout:
-        if len(order) > 1:
-            tree[src] = order[1:]
-        return tree
-    for i, parent in enumerate(order):
-        kids = order[2 * i + 1: 2 * i + 3]
-        if kids:
-            tree[parent] = kids
-    return tree

@@ -1,13 +1,14 @@
 """Multi-process ClusterExecutor: placement fidelity, bit-identity vs the
-per-task executor, XFER endpoints, strategy selection, IPC calibration."""
+per-task executor, XFER endpoints, strategy selection, IPC calibration,
+audit stats."""
 import numpy as np
 import pytest
 
 from repro.core import (ClusteredMatrix as CM, CMMEngine, TimeModel,
                         analytic_time_model, c5_9xlarge)
 from repro.core.machine import hetero_spec
-from repro.exec import EXECUTORS, make_executor
-from repro.exec.cluster import ClusterExecutor, predict_cluster_makespan
+from repro.exec import EXECUTORS, ClusterExecutor, make_executor
+from repro.exec.cluster import predict_cluster_makespan
 from repro.exec.local import LocalExecutor
 
 TM = analytic_time_model()
@@ -74,6 +75,28 @@ def test_cluster_refcounting_frees_all_buffers():
     assert ex_keep.stats["cur_buffer_bytes"] > 0
 
 
+def test_cluster_stats_carry_audit_keys():
+    """The arena audit rides on the run's stats: after a clean one-shot
+    run every node reported and no buffer is live; in session mode each
+    run audits the surviving arenas, and ``retained_total`` counts the
+    session's tiles there."""
+    from repro.core.session import CMMSession
+    spec = hetero_spec((2, 1), **FAST_NET)
+    ex = ClusterExecutor()
+    ex.execute(_plan(_synth(48), tile=16, spec=spec))
+    assert ex.stats["nodes"] == spec.n_nodes
+    assert ex.stats["live_buffers"] == 0
+    assert ex.stats["retained_total"] == 0
+
+    with CMMSession(CMMEngine(spec, TM), executor="cluster", tile=16) as s:
+        P = s.persist(CM.rand(48, 48, seed=0))          # 3 x 3 tiles
+        s.persist(P @ CM.rand(48, 1, seed=1))           # 3 x 1 tiles
+        st = s.stats["last_exec"]
+        assert st["nodes"] == spec.n_nodes
+        assert st["live_buffers"] == 0
+        assert st["retained_total"] == 9 + 3
+
+
 def test_cluster_input_leaves_and_plan_cache_rebind():
     """INPUT data is shipped to the worker processes; a plan-cache hit must
     rebind fresh leaves (different data) through the same schedule."""
@@ -122,8 +145,8 @@ def test_schedule_node_tasks_and_xfer_endpoints():
 # -- executor registry (satellite fix) --------------------------------------
 
 def test_executor_registry_single_source_of_truth():
-    assert {"local", "kernel", "batched", "batched-pallas",
-            "cluster"} <= set(EXECUTORS)
+    assert set(EXECUTORS) == {"local", "batched", "batched-pallas",
+                              "cluster"}
     assert isinstance(make_executor("cluster"), ClusterExecutor)
     assert isinstance(make_executor("local"), LocalExecutor)
     with pytest.raises(ValueError, match="unknown executor"):
@@ -291,10 +314,9 @@ if HAVE_HYP:
 
 
 def test_host_workers_never_import_jax():
-    """The cluster and elastic worker processes and the calibrate_ipc echo
-    child run on the host only: with every JAX import refused (the forked
-    children inherit the refusal), all three still run to the right
-    answer.  A process that drives the chip must still not fork them —
+    """The cluster worker processes and the calibrate_ipc echo child run
+    on the host only: with every JAX import refused (the forked children
+    inherit the refusal), both still run to the right answer.  A process that drives the chip must still not fork them —
     the chip belongs to one process."""
     import os
     import subprocess
@@ -321,9 +343,8 @@ def test_host_workers_never_import_jax():
         expr = ((A @ B) - 0.5).relu() @ A.T
         ref = expr.eager()
         eng = CMMEngine(spec, tm, plan_cache=False)
-        for name in ("cluster", "elastic"):
-            out = eng.run(expr, tile=16, executor=name)
-            np.testing.assert_allclose(out, ref, rtol=1e-10, atol=1e-10)
+        out = eng.run(expr, tile=16, executor="cluster")
+        np.testing.assert_allclose(out, ref, rtol=1e-10, atol=1e-10)
         calibrate_ipc(tm, nbytes=1 << 16, reps=1)
         assert "jax" not in sys.modules
         print("ok")

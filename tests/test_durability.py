@@ -333,7 +333,7 @@ from repro.exec.elastic import ChaosEvent
 
 d = sys.argv[1]
 spec = hetero_spec((3, 2, 1), link_bw=1e12, latency=1e-6)
-s = CMMSession(CMMEngine(spec, analytic_time_model()), executor="elastic",
+s = CMMSession(CMMEngine(spec, analytic_time_model()), executor="cluster",
                tile=16, checkpoint_dir=d)
 A = s.persist(CM.rand(48, 48, seed=0), name="A")
 u = s.persist(CM.rand(48, 1, seed=1), name="u")
@@ -358,20 +358,29 @@ def test_full_cluster_sigkill_then_resume_bitwise(tmp_path):
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(os.path.dirname(__file__), "..", "src"),
          env.get("PYTHONPATH", "")])
-    p = subprocess.run([sys.executable, str(child), str(tmp_path)],
-                       env=env, capture_output=True, text=True, timeout=240)
-    assert p.returncode == -signal.SIGKILL, (p.returncode, p.stderr[-800:])
-    assert "flushed" in p.stdout
-    assert "UNREACHABLE" not in p.stdout
-    # reap shared memory the killed cluster may have stranded
-    for f in glob.glob("/dev/shm/cmm*"):
+    p = subprocess.Popen([sys.executable, str(child), str(tmp_path)],
+                         env=env, stdout=subprocess.PIPE,
+                         stderr=subprocess.PIPE, text=True)
+    try:
+        stdout, stderr = p.communicate(timeout=240)
+    finally:
+        if p.poll() is None:            # pragma: no cover — timed out
+            p.kill()
+            p.communicate()
+    assert p.returncode == -signal.SIGKILL, (p.returncode, stderr[-800:])
+    assert "flushed" in stdout
+    assert "UNREACHABLE" not in stdout
+    # reap shared memory the killed cluster may have stranded: only its
+    # own segments (named after the killed master's pid) — other
+    # processes' clusters may be running alongside
+    for f in glob.glob(f"/dev/shm/cmm{p.pid}_*"):
         try:
             os.unlink(f)
         except OSError:
             pass
 
     with CMMSession.resume(str(tmp_path), _engine(SPEC2),
-                           executor="elastic", tile=16) as s:
+                           executor="cluster", tile=16) as s:
         rep = s.stats["resume"]
         assert sorted(rep["reloaded"] + rep["recomputed"])
         got = s.compute(s.resident("A") @ s.resident("u1"))
@@ -391,7 +400,7 @@ def _power_chain_ref(n, k):
 def test_elastic_resume_onto_different_spec_bitwise(tmp_path):
     """Durable elastic session on a 3-node cluster, resumed onto a 2-node
     cluster: tiles re-home into the new arenas, bytes unchanged."""
-    with CMMSession(_engine(SPEC3), executor="elastic", tile=16,
+    with CMMSession(_engine(SPEC3), executor="cluster", tile=16,
                     checkpoint_dir=str(tmp_path)) as s:
         A = s.persist(CM.rand(48, 48, seed=0), name="A")
         u = s.persist(CM.rand(48, 1, seed=1), name="u")
@@ -399,7 +408,7 @@ def test_elastic_resume_onto_different_spec_bitwise(tmp_path):
         ref = u.to_numpy()
         s.flush_checkpoints()
     with CMMSession.resume(str(tmp_path), _engine(SPEC2),
-                           executor="elastic", tile=16,
+                           executor="cluster", tile=16,
                            policy="reload") as s2:
         h = s2.resident("u0").handle
         assert set(h.home.values()) <= set(SPEC2.alive_nodes())
@@ -412,7 +421,7 @@ def test_chaos_corrupt_tile_degrades_on_resume(tmp_path):
     shard mid-run; the next resume detects the CRC mismatch and degrades
     that handle to lineage recompute — no wrong bytes survive."""
     from repro.exec.elastic import ChaosEvent
-    with CMMSession(_engine(SPEC2), executor="elastic", tile=16,
+    with CMMSession(_engine(SPEC2), executor="cluster", tile=16,
                     checkpoint_dir=str(tmp_path)) as s:
         A = s.persist(CM.rand(48, 48, seed=0), name="A")
         u = s.persist(A @ CM.rand(48, 1, seed=1), name="u0")
@@ -432,7 +441,7 @@ def test_chaos_corrupt_tile_degrades_on_resume(tmp_path):
 
 def test_chaos_corrupt_tile_requires_durable_session():
     from repro.exec.elastic import ChaosEvent
-    with CMMSession(_engine(SPEC2), executor="elastic", tile=16) as s:
+    with CMMSession(_engine(SPEC2), executor="cluster", tile=16) as s:
         s._exec.chaos = [ChaosEvent(after_done=0, corrupt_tile=1)]
         with pytest.raises(ValueError, match="durable session"):
             s.compute(CM.rand(32, 32, seed=0))
@@ -446,7 +455,7 @@ def test_chaos_dropped_xfer_retries_and_stays_bitwise():
     holder) and the result is still bitwise correct — no hang, no wrong
     bytes."""
     from repro.exec.elastic import ChaosEvent
-    with CMMSession(_engine(SPEC3), executor="elastic", tile=16) as s:
+    with CMMSession(_engine(SPEC3), executor="cluster", tile=16) as s:
         s._exec.chaos = [ChaosEvent(after_done=1, drop_xfer=2)]
         A = s.persist(CM.rand(96, 96, seed=0), name="A")
         B = s.persist(CM.rand(96, 96, seed=2), name="B")
@@ -466,7 +475,7 @@ def test_durable_session_survives_node_death_and_checkpoints(tmp_path):
     the handle AND the next snapshot captures the re-homed tiles, so a
     later resume sees the post-recovery state."""
     from repro.exec.elastic import ChaosEvent
-    with CMMSession(_engine(SPEC2), executor="elastic", tile=16,
+    with CMMSession(_engine(SPEC2), executor="cluster", tile=16,
                     checkpoint_dir=str(tmp_path)) as s:
         A = s.persist(CM.rand(96, 96, seed=0) @ CM.rand(96, 96, seed=1),
                       name="A")

@@ -17,7 +17,7 @@ from repro.core.machine import hetero_spec
 from repro.core.simulator import (churn_adjusted_makespan,
                                   predict_recovery_cost)
 from repro.exec import EXECUTORS, make_executor
-from repro.exec.elastic import ChaosEvent, ElasticClusterExecutor
+from repro.exec.elastic import ChaosEvent, ClusterExecutor
 from repro.exec.local import LocalExecutor
 from repro.runtime.membership import (DEATH, RECOVER, STRAGGLE,
                                       MembershipConfig, MembershipService)
@@ -311,16 +311,16 @@ def test_plan_cache_invalidated_by_recalibration():
 # -- engine integration ------------------------------------------------------
 
 def test_elastic_registered_and_engine_runs_it():
-    assert "elastic" in EXECUTORS
-    assert isinstance(make_executor("elastic"), ElasticClusterExecutor)
+    assert "cluster" in EXECUTORS
+    assert isinstance(make_executor("cluster"), ClusterExecutor)
     spec = hetero_spec((2, 1), **FAST_NET)
     eng = CMMEngine(spec, TM, plan_cache=False)
     expr = _synth(48)
-    out = eng.run(expr, tile=16, executor="elastic")
+    out = eng.run(expr, tile=16, executor="cluster")
     plan = eng.plan(expr, tile=16)
     assert np.array_equal(out, LocalExecutor().execute(plan))
     assert eng.last_exec_stats["deaths"] == 0
-    assert eng.last_exec_stats["executor"] == "elastic"
+    assert eng.last_exec_stats["executor"] == "cluster"
 
 
 def test_engine_elastic_auto_prices_churn():
@@ -334,17 +334,17 @@ def test_engine_elastic_auto_prices_churn():
     spec = hetero_spec((2, 1), **FAST_NET)
     eng = CMMEngine(spec, tm, plan_cache=False, elastic=True)
     plan = eng.plan(expr, tile=16)
-    # reliable cluster: the elastic strategy wins and runs elastically
-    assert eng.choose_executor(plan) == "elastic"
+    # reliable cluster: the churn-priced cluster strategy wins and runs
+    assert eng.choose_executor(plan) == "cluster"
     out = eng.run(expr, plan=plan, executor="auto", validate=True)
-    assert eng.last_exec_stats["executor"] == "elastic"
+    assert eng.last_exec_stats["executor"] == "cluster"
     assert out.shape == (48, 48)
     # an unreliable cluster tips auto back to an in-process strategy
     tm.node_mtbf = 1e-3
     tm.respawn_overhead = 1e3
     plan2 = eng.plan(expr, tile=16)
     assert plan2.elastic_makespan > plan2.cluster_makespan
-    assert eng.choose_executor(plan2) != "elastic"
+    assert eng.choose_executor(plan2) != "cluster"
 
 
 # -- fault-injected execution: the acceptance bar ---------------------------
@@ -357,7 +357,7 @@ def test_kill_one_node_mid_run_bitwise():
     plan = _plan(_synth(), tile=16, spec=HET_SPEC)
     ref = LocalExecutor().execute(plan)
     kill_at = len(plan.program.graph) // 3
-    ex = ElasticClusterExecutor(
+    ex = ClusterExecutor(
         timemodel=TM, chaos=[ChaosEvent(after_done=kill_at, kill_node=1)])
     out = ex.execute(plan)
     assert out.dtype == ref.dtype
@@ -373,12 +373,12 @@ def test_kill_one_node_mid_run_bitwise():
 
 def test_chaos_kill_node_must_be_in_range():
     plan = _plan(_synth(48), tile=16, spec=hetero_spec((2, 1), **FAST_NET))
-    ex = ElasticClusterExecutor(
+    ex = ClusterExecutor(
         timemodel=TM, chaos=[ChaosEvent(after_done=1, kill_node=7)])
     with pytest.raises(ValueError, match="kill_node=7"):
         ex.execute(plan)
     with pytest.raises(ValueError, match="master"):
-        ElasticClusterExecutor(
+        ClusterExecutor(
             timemodel=TM,
             chaos=[ChaosEvent(after_done=1, kill_node=0)]).execute(plan)
 
@@ -390,7 +390,7 @@ def test_kill_of_later_joining_node_is_deferred_not_dropped():
     spec = hetero_spec((1, 1), **FAST_NET)
     plan = _plan(_synth(), tile=8, spec=spec)
     ref = LocalExecutor().execute(plan)
-    ex = ElasticClusterExecutor(
+    ex = ClusterExecutor(
         timemodel=TM,
         chaos=[ChaosEvent(after_done=1, kill_node=2),     # before the join
                ChaosEvent(after_done=6, join_workers=2)])
@@ -404,7 +404,7 @@ def test_kill_of_later_joining_node_is_deferred_not_dropped():
 @pytest.mark.chaos
 def test_min_nodes_floor_aborts_run():
     plan = _plan(_synth(48), tile=16, spec=hetero_spec((2, 2), **FAST_NET))
-    ex = ElasticClusterExecutor(
+    ex = ClusterExecutor(
         timemodel=TM, timeout=60,
         membership=MembershipConfig(min_nodes=2),
         chaos=[ChaosEvent(after_done=5, kill_node=1)])
@@ -429,7 +429,7 @@ def test_stall_watchdog_fires_despite_heartbeats():
     g.add_edge(t2.tid, t1.tid)           # cycle: neither can ever start
     plan.schedule.placements[t1.tid] = Placement(0, 0, 1e9, 1e9)
     plan.schedule.placements[t2.tid] = Placement(0, 0, 1e9, 1e9)
-    ex = ElasticClusterExecutor(
+    ex = ClusterExecutor(
         timemodel=TM, timeout=3.0,
         # straggler detection stays off: on a loaded host the idle wedged
         # run can trip a STRAGGLE sweep first, and the resulting replan
@@ -444,7 +444,7 @@ def test_stall_watchdog_fires_despite_heartbeats():
 def test_kill_respawn_readmits_node():
     plan = _plan(_synth(), tile=16, spec=HET_SPEC)
     ref = LocalExecutor().execute(plan)
-    ex = ElasticClusterExecutor(
+    ex = ClusterExecutor(
         timemodel=TM, respawn_dead=True,
         chaos=[ChaosEvent(after_done=12, kill_node=2)])
     out = ex.execute(plan)
@@ -459,7 +459,7 @@ def test_join_node_mid_run_bitwise_and_used():
     spec = hetero_spec((1, 1), **FAST_NET)
     plan = _plan(_synth(), tile=8, spec=spec)   # 8x8 grid: plenty of work
     ref = LocalExecutor().execute(plan)
-    ex = ElasticClusterExecutor(
+    ex = ClusterExecutor(
         timemodel=TM,
         chaos=[ChaosEvent(after_done=10, join_workers=3)])
     out = ex.execute(plan)
@@ -475,7 +475,7 @@ def test_join_node_mid_run_bitwise_and_used():
 def test_straggler_speculation_bitwise():
     plan = _plan(_synth(), tile=16, spec=HET_SPEC)
     ref = LocalExecutor().execute(plan)
-    ex = ElasticClusterExecutor(
+    ex = ClusterExecutor(
         timemodel=TM,
         chaos=[ChaosEvent(after_done=3, throttle_node=1,
                           throttle_seconds=0.05),
@@ -517,7 +517,7 @@ if HAVE_HYP:
         total = len(plan.program.graph)
         kill_at = data.draw(st.integers(1, max(1, total - 2)))
         ref = LocalExecutor().execute(plan)
-        ex = ElasticClusterExecutor(
+        ex = ClusterExecutor(
             timemodel=TM,
             chaos=[ChaosEvent(after_done=kill_at, kill_node=1)])
         out = ex.execute(plan)
@@ -541,7 +541,7 @@ if HAVE_HYP:
         total = len(plan.program.graph)
         join_at = data.draw(st.integers(0, max(0, total - 2)))
         ref = LocalExecutor().execute(plan)
-        ex = ElasticClusterExecutor(
+        ex = ClusterExecutor(
             timemodel=TM,
             chaos=[ChaosEvent(after_done=join_at, join_workers=2)])
         out = ex.execute(plan)
@@ -569,7 +569,7 @@ if HAVE_HYP:
         total = len(plan.program.graph)
         kill_at = data.draw(st.integers(1, max(1, total - 2)))
         ref = LocalExecutor().execute(plan)
-        ex = ElasticClusterExecutor(
+        ex = ClusterExecutor(
             timemodel=TM,
             chaos=[ChaosEvent(after_done=kill_at, kill_node=1)])
         out = ex.execute(plan)
@@ -598,7 +598,7 @@ def test_paper_suite_kill_one_node_bitwise():
         plan = eng.plan(expr, tile=16)
         ref = LocalExecutor().execute(plan)
         kill_at = max(1, len(plan.program.graph) // 3)
-        ex = ElasticClusterExecutor(
+        ex = ClusterExecutor(
             timemodel=TM,
             chaos=[ChaosEvent(after_done=kill_at, kill_node=1)])
         out = ex.execute(plan)

@@ -91,14 +91,15 @@ def test_flash_attention_bf16():
 
 
 def test_kernel_used_by_executor():
-    """kernel executor path: tiled CMM execution through Pallas addmul."""
+    """Device executor path: tiled CMM execution through the Pallas
+    addmul, batched per wave."""
     from repro.core import CMMEngine, ClusteredMatrix as CM, c5_9xlarge
     from repro.core import analytic_time_model
     rng = np.random.default_rng(5)
     a = rng.standard_normal((96, 96))
     A = CM.from_array(a)
     eng = CMMEngine(c5_9xlarge(1), analytic_time_model(), tile=48)
-    out = eng.run(A @ A, executor="kernel")
+    out = eng.run(A @ A, executor="batched-pallas")
     np.testing.assert_allclose(out, a @ a, rtol=1e-4, atol=1e-4)
 
 

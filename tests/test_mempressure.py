@@ -20,8 +20,7 @@ from repro.core.heft import min_resident_floor, peak_node_bytes
 from repro.core.machine import MemoryBudgetExceeded, hetero_spec
 from repro.core.session import CMMSession
 from repro.core.simulator import predict_spill_seconds
-from repro.exec.cluster import ClusterExecutor
-from repro.exec.elastic import ChaosEvent, ElasticClusterExecutor
+from repro.exec.elastic import ChaosEvent, ClusterExecutor
 from repro.runtime.spill import (SpillCorrupt, SpillMiss, TileSpillStore,
                                  run_spill_dir)
 
@@ -220,17 +219,20 @@ def test_admission_replans_smaller_tile_out_of_core():
 
 @pytest.mark.slow
 @pytest.mark.mempressure
-def test_cluster_bounded_bitwise_vs_unbounded():
+@pytest.mark.parametrize("tm", [None, TM], ids=["own-tm", "engine-tm"])
+def test_cluster_bounded_bitwise_vs_unbounded(tm):
     """Acceptance: footprint >= 2x per-node budget completes bitwise
-    equal to the unbounded oracle on the static cluster executor."""
-    ref = ClusterExecutor().execute(_plan(SPEC3))
-    ex = ClusterExecutor()
+    equal to the unbounded oracle on the cluster executor, with its own
+    model or one handed in."""
+    ref = ClusterExecutor(timemodel=tm).execute(_plan(SPEC3))
+    ex = ClusterExecutor(timemodel=tm)
     out = ex.execute(_plan(_bounded_spec(WS // 3)))
     assert np.array_equal(ref, out)
     assert ex.stats["spill_writes"] > 0, "budget never exercised the spill"
     assert ex.stats["faults"] > 0
     assert ex.stats["leaked_spill_files"] == 0
     assert ex.stats["live_buffers"] == 0
+    assert ex.stats["tiles_lost"] == 0
 
 
 @pytest.mark.mempressure
@@ -255,25 +257,13 @@ def test_cluster_bounded_xfer_heavy_chain_bitwise():
 
 
 @pytest.mark.slow
-@pytest.mark.mempressure
-def test_elastic_bounded_bitwise_vs_unbounded():
-    ref = ElasticClusterExecutor(timemodel=TM).execute(_plan(SPEC3))
-    ex = ElasticClusterExecutor(timemodel=TM)
-    out = ex.execute(_plan(_bounded_spec(WS // 3)))
-    assert np.array_equal(ref, out)
-    assert ex.stats["spill_writes"] > 0
-    assert ex.stats["leaked_spill_files"] == 0
-    assert ex.stats["tiles_lost"] == 0
-
-
-@pytest.mark.slow
 @pytest.mark.chaos
 @pytest.mark.mempressure
 def test_elastic_mem_squeeze_midrun_bitwise():
     """Shrinking a node's budget mid-run forces eviction; the run stays
     bitwise correct and current_spec reflects the squeeze."""
-    ref = ElasticClusterExecutor(timemodel=TM).execute(_plan(SPEC3))
-    ex = ElasticClusterExecutor(
+    ref = ClusterExecutor(timemodel=TM).execute(_plan(SPEC3))
+    ex = ClusterExecutor(
         timemodel=TM,
         chaos=(ChaosEvent(after_done=5, mem_squeeze=1,
                           squeeze_bytes=WS // 6),))
@@ -290,8 +280,8 @@ def test_elastic_mem_squeeze_midrun_bitwise():
 def test_elastic_alloc_fail_retries_bitwise():
     """An injected allocation failure rides the bounded task/XFER retry
     path — the master recovers, never crashes."""
-    ref = ElasticClusterExecutor(timemodel=TM).execute(_plan(SPEC3))
-    ex = ElasticClusterExecutor(
+    ref = ClusterExecutor(timemodel=TM).execute(_plan(SPEC3))
+    ex = ClusterExecutor(
         timemodel=TM,
         chaos=(ChaosEvent(after_done=3, alloc_fail=0, alloc_fail_nth=2),))
     out = ex.execute(_plan(SPEC3))
@@ -306,7 +296,7 @@ def test_elastic_squeeze_to_nothing_is_structured_error():
     """A squeeze below one tile's working set can never be survived —
     the run must fail with MemoryBudgetExceeded naming the node, not an
     OOM kill or a hang."""
-    ex = ElasticClusterExecutor(
+    ex = ClusterExecutor(
         timemodel=TM, timeout=120.0,
         chaos=(ChaosEvent(after_done=2, mem_squeeze=1,
                           squeeze_bytes=64),))
@@ -321,8 +311,8 @@ def test_elastic_squeeze_to_nothing_is_structured_error():
 def test_elastic_kill_composes_with_bounded_arena():
     """Spill/fault-in composes with the existing kill chaos: lineage
     recovery under a budget stays bitwise."""
-    ref = ElasticClusterExecutor(timemodel=TM).execute(_plan(SPEC3))
-    ex = ElasticClusterExecutor(
+    ref = ClusterExecutor(timemodel=TM).execute(_plan(SPEC3))
+    ex = ClusterExecutor(
         timemodel=TM, chaos=(ChaosEvent(after_done=6, kill_node=2),))
     out = ex.execute(_plan(_bounded_spec(WS // 2)))
     assert np.array_equal(ref, out)
@@ -370,7 +360,7 @@ def test_session_elastic_squeeze_persisted_workload():
     and close() audits clean (no leaked spill files)."""
     ref = _power_refs(64, 3, 16)
     eng = CMMEngine(_bounded_spec(WS), TM)
-    s = CMMSession(eng, executor="elastic", tile=16)
+    s = CMMSession(eng, executor="cluster", tile=16)
     s._exec.chaos = (ChaosEvent(after_done=4, mem_squeeze=1,
                                 squeeze_bytes=WS // 4),)
     P = s.persist(CM.rand(64, 64, seed=0))

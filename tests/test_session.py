@@ -340,7 +340,7 @@ def test_elastic_session_recomputes_lost_resident_from_lineage():
     from repro.exec.elastic import ChaosEvent
     spec = hetero_spec((2, 2), link_bw=1e12, latency=1e-6)
     eng = _engine(spec)
-    s = CMMSession(eng, executor="elastic", tile=16)
+    s = CMMSession(eng, executor="cluster", tile=16)
     try:
         A = s.persist(CM.rand(96, 96, seed=0) @ CM.rand(96, 96, seed=1),
                       name="A")
@@ -374,7 +374,7 @@ def test_elastic_session_marks_unused_handles_lost():
     from repro.exec.elastic import ChaosEvent
     spec = hetero_spec((2, 2), link_bw=1e12, latency=1e-6)
     eng = _engine(spec)
-    with CMMSession(eng, executor="elastic", tile=16) as s:
+    with CMMSession(eng, executor="cluster", tile=16) as s:
         Q = s.persist(CM.rand(96, 96, seed=2) @ CM.rand(96, 96, seed=3),
                       name="Q")
         assert 1 in set(Q.handle.home.values())
@@ -395,7 +395,7 @@ def test_elastic_session_three_runs_bitwise():
     tiles, bitwise vs the one-shot path, clean audit."""
     spec = hetero_spec((2, 2), link_bw=1e12, latency=1e-6)
     eng = _engine(spec)
-    with CMMSession(eng, executor="elastic", tile=16) as s:
+    with CMMSession(eng, executor="cluster", tile=16) as s:
         P = s.persist(CM.rand(48, 48, seed=0))
         u = s.persist(CM.rand(48, 1, seed=1))
         for _ in range(3):

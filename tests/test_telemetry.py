@@ -327,7 +327,7 @@ def test_tracing_off_is_bit_identical_and_silent():
 def test_session_trace_accumulates_and_exports(tmp_path):
     spec = hetero_spec((2, 2, 1))
     A = CM.rand(48, 48, seed=0)
-    with CMMSession(CMMEngine(spec, TM), executor="elastic", tile=24) as s:
+    with CMMSession(CMMEngine(spec, TM), executor="cluster", tile=24) as s:
         P = s.persist(A @ A)
         one = len(s.spans)
         assert one > 0

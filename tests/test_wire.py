@@ -16,13 +16,13 @@ import pytest
 from repro.core import (ClusteredMatrix as CM, CMMEngine, TimeModel,
                         analytic_time_model)
 from repro.core.machine import hetero_spec
+from repro.core.simulator import _relay_tree
 from repro.core.timemodel import TimeModel as TM_cls
-from repro.exec.cluster import ClusterExecutor
-from repro.exec.elastic import ChaosEvent, ElasticClusterExecutor
+from repro.exec.elastic import ChaosEvent, ClusterExecutor
 from repro.exec.local import LocalExecutor
 from repro.runtime.membership import MembershipConfig
-from repro.runtime.wire import (BCAST_MIN_FANOUT, CODECS, broadcast_tree,
-                                choose_wire_codec, decode_tile, encode_tile)
+from repro.runtime.wire import (CODECS, choose_wire_codec, decode_tile,
+                                encode_tile)
 
 TM = analytic_time_model()
 FAST_NET = dict(link_bw=1e12, latency=1e-6)
@@ -167,15 +167,10 @@ def test_calibrate_compression_fits_sane_terms():
     assert tm.compression_ratio_prior == ratio and ratio > 1.0
 
 
-# -- broadcast tree shape ----------------------------------------------------
-
-def test_broadcast_tree_flat_below_fanout():
-    dsts = list(range(1, BCAST_MIN_FANOUT))
-    assert broadcast_tree(0, dsts) == {0: dsts}
-
+# -- the simulator's relay tree shape -----------------------------------------
 
 def test_broadcast_tree_structure():
-    tree = broadcast_tree(0, [1, 2, 3, 4, 5])
+    tree = _relay_tree(0, [1, 2, 3, 4, 5])
     # every destination appears exactly once as a child
     kids = [c for cs in tree.values() for c in cs]
     assert sorted(kids) == [1, 2, 3, 4, 5]
@@ -185,33 +180,24 @@ def test_broadcast_tree_structure():
 
 
 def test_broadcast_tree_excludes_source_from_dsts():
-    tree = broadcast_tree(2, [0, 1, 2])
+    tree = _relay_tree(2, [0, 1, 2])
     kids = [c for cs in tree.values() for c in cs]
     assert 2 not in kids and sorted(kids) == [0, 1]
 
 
 # -- executor conformance: compression on the real transfer path ------------
 
-@pytest.mark.parametrize("codec", ["raw", "zlib"])
-def test_cluster_forced_codec_bit_identical(codec):
+@pytest.mark.parametrize(
+    "codec,tm", [("raw", None), ("zlib", None), ("raw", TM), ("zlib", TM)],
+    ids=["raw", "zlib", "raw-engine-tm", "zlib-engine-tm"])
+def test_cluster_forced_codec_bit_identical(codec, tm):
+    """A forced codec is used on every cross-node edge, whether the
+    executor falls back to its own model or is handed one, as the engine
+    hands it its TimeModel."""
     spec = hetero_spec((2, 2, 1), **FAST_NET)
     plan = _plan(_synth(), tile=16, spec=spec)
     ref = LocalExecutor().execute(plan)
-    ex = ClusterExecutor(wire_codec=codec)
-    out = ex.execute(plan)
-    assert np.array_equal(ref, out)
-    if codec == "zlib" and ex.stats["xfers"] > 0:
-        assert ex.stats["xfers_compressed"] > 0
-        assert ex.stats["wire_bytes"] < ex.stats["xfer_bytes"]
-    assert ex.stats["stale_leases"] == 0
-
-
-@pytest.mark.parametrize("codec", ["raw", "zlib"])
-def test_elastic_forced_codec_bit_identical(codec):
-    spec = hetero_spec((2, 2, 1), **FAST_NET)
-    plan = _plan(_synth(), tile=16, spec=spec)
-    ref = LocalExecutor().execute(plan)
-    ex = ElasticClusterExecutor(timemodel=TM, wire_codec=codec)
+    ex = ClusterExecutor(timemodel=tm, wire_codec=codec)
     out = ex.execute(plan)
     assert np.array_equal(ref, out)
     if codec == "zlib" and ex.stats["xfers"] > 0:
@@ -243,6 +229,31 @@ def test_auto_pricing_compresses_on_slow_links_only():
     assert ex2.stats["xfers_compressed"] == 0
 
 
+def test_engine_and_session_price_cluster_wire_with_their_timemodel():
+    """The engine hands its TimeModel to a one-shot cluster run, and a
+    cluster session defaults to it too: with fitted compression terms on
+    a slow link every cross-node XFER is priced into zlib."""
+    from repro.core.session import CMMSession
+    tm = analytic_time_model()
+    tm.compress_bandwidth = 1e9
+    tm.compression_ratio_prior = 4.0
+    eng = CMMEngine(hetero_spec((2, 1), link_bw=1e4, latency=1e-6), tm,
+                    plan_cache=False)
+    A, B = CM.rand(48, 48, seed=0), CM.rand(48, 48, seed=1)
+    out = eng.run((A @ B) + A, tile=16, executor="cluster")
+    st = eng.last_exec_stats
+    assert st["xfers"] > 0 and st["xfers_compressed"] == st["xfers"]
+    assert np.array_equal(out, eng.run((A @ B) + A, tile=16))
+
+    with CMMSession(eng, executor="cluster", tile=16) as s:
+        assert s._exec.timemodel is tm
+        P = s.persist((A @ B) + A)
+        got = s.compute(P @ A)
+        st = s.stats["last_exec"]
+        assert st["xfers"] > 0 and st["xfers_compressed"] == st["xfers"]
+    assert np.array_equal(got, eng.run(((A @ B) + A) @ A, tile=16))
+
+
 # -- broadcast + streaming gather on executors ------------------------------
 
 def test_cluster_broadcast_relays_and_matches():
@@ -258,29 +269,38 @@ def test_cluster_broadcast_relays_and_matches():
     assert ex2.stats["relay_hops"] == 0
 
 
-def test_cluster_stream_gather_bit_identical():
+def test_cluster_fanout_relays_without_chaos():
+    """With no churn, a fan-out edge still drains through relays: landed
+    copies serve later consumers (relay hops), bitwise, every lease
+    closed.  Two workers a node keep several copies of a tile in flight
+    at once, so holders reach their relay cap."""
+    spec = hetero_spec((2, 2, 2, 2, 2, 2), **FAST_NET)
+    plan = _plan(_fanout_expr(), tile=16, spec=spec)
+    ref = LocalExecutor().execute(plan)
+    ex = ClusterExecutor(broadcast=True)
+    out = ex.execute(plan)
+    assert np.array_equal(ref, out)
+    assert ex.stats["deaths"] == 0
+    assert ex.stats["relay_hops"] > 0
+    assert ex.stats["stale_leases"] == 0
+
+
+@pytest.mark.parametrize("tm", [None, TM], ids=["own-tm", "engine-tm"])
+def test_cluster_stream_gather_bit_identical(tm):
+    """Streamed and barrier gathers agree bitwise, with the executor's own
+    model or one handed in."""
     spec = hetero_spec((2, 2), **FAST_NET)
     plan = _plan(_synth(96), tile=16, spec=spec)
     ref = LocalExecutor().execute(plan)
-    on = ClusterExecutor(stream_gather=True)
+    on = ClusterExecutor(timemodel=tm, stream_gather=True)
     out_on = on.execute(plan)
-    off = ClusterExecutor(stream_gather=False)
+    off = ClusterExecutor(timemodel=tm, stream_gather=False)
     out_off = off.execute(plan)
     assert np.array_equal(ref, out_on) and np.array_equal(ref, out_off)
     assert on.stats["gather_streamed_tiles"] > 0
     assert off.stats["gather_streamed_tiles"] == 0
     assert on.stats["gather_first_tile_s"] is not None
     assert on.stats["gather_full_result_s"] >= on.stats["gather_first_tile_s"]
-
-
-def test_elastic_stream_gather_bit_identical():
-    spec = hetero_spec((2, 2), **FAST_NET)
-    plan = _plan(_synth(96), tile=16, spec=spec)
-    ref = LocalExecutor().execute(plan)
-    ex = ElasticClusterExecutor(timemodel=TM, stream_gather=True)
-    out = ex.execute(plan)
-    assert np.array_equal(ref, out)
-    assert ex.stats["gather_streamed_tiles"] > 0
 
 
 # -- chaos: leases and relays under churn -----------------------------------
@@ -298,7 +318,7 @@ def test_consumer_death_mid_copy_releases_source_leases():
     spec = hetero_spec((2, 2, 1, 1), mem_bytes=float(ws), **FAST_NET)
     plan = _plan(_fanout_expr(n), tile=16, spec=spec)
     ref = LocalExecutor().execute(plan)
-    ex = ElasticClusterExecutor(
+    ex = ClusterExecutor(
         timemodel=TM,
         membership=MembershipConfig(heartbeat_interval_s=0.05),
         chaos=[ChaosEvent(after_done=0, throttle_node=3,
@@ -320,7 +340,7 @@ def test_relay_node_death_rebuilds_broadcast_tree():
     spec = hetero_spec((1, 1, 1, 1, 1, 1), **FAST_NET)
     plan = _plan(_fanout_expr(), tile=16, spec=spec)
     ref = LocalExecutor().execute(plan)
-    ex = ElasticClusterExecutor(
+    ex = ClusterExecutor(
         timemodel=TM, broadcast=True,
         chaos=[ChaosEvent(after_done=14, kill_node=4)])
     out = ex.execute(plan)
@@ -337,7 +357,7 @@ def test_compressed_xfers_survive_chaos_drops():
     spec = hetero_spec((2, 2, 1), **FAST_NET)
     plan = _plan(_synth(), tile=16, spec=spec)
     ref = LocalExecutor().execute(plan)
-    ex = ElasticClusterExecutor(
+    ex = ClusterExecutor(
         timemodel=TM, wire_codec="zlib",
         chaos=[ChaosEvent(after_done=4, drop_xfer=3)])
     out = ex.execute(plan)
@@ -356,7 +376,7 @@ def test_retry_budget_resets_after_successful_retry():
     spec = hetero_spec((2, 2, 1), **FAST_NET)
     plan = _plan(_synth(), tile=16, spec=spec)
     ref = LocalExecutor().execute(plan)
-    ex = ElasticClusterExecutor(
+    ex = ClusterExecutor(
         timemodel=TM,
         membership=MembershipConfig(xfer_max_retries=2),
         chaos=[ChaosEvent(after_done=2, drop_xfer=2),
